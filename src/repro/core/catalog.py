@@ -73,87 +73,102 @@ _FRONTEND_SIZES = {                  # bf16 param bytes of the real frontends
 
 @register_payload("model.decoder")
 def _build_decoder(cfg: ArchConfig, context: Mapping[str, Any], bundle):
-    """Model-family assembler: reads which kernel variants Algorithm 1
-    selected (their context contributions) and composes the model."""
+    """Model-family assembler: composes the model from the kernel callables
+    Algorithm 1 selected (each kernel payload binds this build's interpret
+    mode from the building context)."""
     from ..models import Variants, build_model
+
+    def kernel(name: str):
+        if not bundle.has("kernel", name):
+            return None
+        return bundle.payload("kernel", name)(context)
+
+    picked = {"attn_kernel": kernel("attention"),
+              "wkv_impl": kernel("wkv6"),
+              "rms_norm": kernel("rmsnorm")}
     v = Variants(
-        attn_kernel=context.get("attn.impl", "lax-flash"),
         moe_impl=context.get("moe.impl", "grouped"),
-        wkv_impl=context.get("wkv.impl", "chunked"),
         remat=context.get("remat", "full"),
         capacity_factor=float(context.get("moe.capacity", 1.25)),
         moe_combine=context.get("moe.combine", "f32"),
         moe_slot_dp=bool(context.get("moe.slot_dp", False)),
+        **{k: fn for k, fn in picked.items() if fn is not None},
     )
     return build_model(cfg, v)
 
 
-# -- kernels: payloads expose the impls and register platform variants ------
+# -- kernels: each payload takes the building context and returns the
+# -- callable the model runs (Pallas ones bound to the build's interpret mode)
+
+def _interpret(context: Mapping[str, Any]) -> bool:
+    return bool(context["interpret"])
+
 
 @register_payload("kernel.attention.naive")
-def _k_attn_naive():
+def _k_attn_naive(context):
     from ..models.attention import naive_attention
     return naive_attention
 
 
 @register_payload("kernel.attention.xla_flash")
-def _k_attn_xla():
+def _k_attn_xla(context):
     from ..models.attention import lax_flash_attention
     return lax_flash_attention
 
 
 @register_payload("kernel.attention.pallas")
-def _k_attn_pallas():
+def _k_attn_pallas(context):
     from ..kernels import pallas_attention
-    return pallas_attention
+    return functools.partial(pallas_attention,
+                             interpret=_interpret(context))
 
 
 @register_payload("kernel.wkv6.sequential")
-def _k_wkv_seq():
+def _k_wkv_seq(context):
     from ..models.ssm import wkv6_sequential
     return wkv6_sequential
 
 
 @register_payload("kernel.wkv6.chunked")
-def _k_wkv_chunk():
+def _k_wkv_chunk(context):
     from ..models.ssm import wkv6_chunked
     return wkv6_chunked
 
 
 @register_payload("kernel.wkv6.pallas")
-def _k_wkv_pallas():
+def _k_wkv_pallas(context):
     from ..kernels import pallas_wkv6
-    return pallas_wkv6
+    return functools.partial(pallas_wkv6, interpret=_interpret(context))
 
 
 @register_payload("kernel.moe.grouped")
-def _k_moe_grouped():
+def _k_moe_grouped(context):
     from ..models.ffn import moe_grouped
     return moe_grouped
 
 
 @register_payload("kernel.moe.dense")
-def _k_moe_dense():
+def _k_moe_dense(context):
     from ..models.ffn import moe_dense
     return moe_dense
 
 
 @register_payload("kernel.ssm_scan.lax")
-def _k_ssm():
+def _k_ssm(context):
     from ..models.ssm import mamba_block
     return mamba_block
 
 
 @register_payload("kernel.rmsnorm.xla")
-def _k_rms_xla():
+def _k_rms_xla(context):
     from ..models.common import rms_norm
     return rms_norm
 
 
 @register_payload("kernel.rmsnorm.pallas")
-def _k_rms_pallas():
+def _k_rms_pallas(context):
     from ..kernels import pallas_rmsnorm
-    return pallas_rmsnorm
+    return functools.partial(pallas_rmsnorm, interpret=_interpret(context))
 
 
 # -- parallel plans ----------------------------------------------------------
@@ -299,7 +314,8 @@ def _build_serve_entry(model, cfg: ArchConfig, context, bundle, mesh=None):
 
     def param_shardings():
         from ..models.common import P as PSpec
-        return jax.tree.map(lambda p: plan.sharding(p.axes), model.specs,
+        return jax.tree.map(lambda p: plan.sharding(p.axes, p.shape),
+                            model.specs,
                             is_leaf=lambda x: isinstance(x, PSpec))
 
     return {
@@ -316,8 +332,10 @@ def _build_serve_entry(model, cfg: ArchConfig, context, bundle, mesh=None):
 def _build_batcher(model, cfg: ArchConfig, context, bundle, mesh=None):
     from ..serving import ServingEngine
 
+    plan = _build_plan(context.get("plan.rules", "tp"), mesh)
+
     def make_engine(params, **kw):
-        return ServingEngine(model, params, **kw)
+        return ServingEngine(model, params, plan=plan, **kw)
 
     return {"make_engine": make_engine}
 

@@ -130,6 +130,21 @@ class Lockfile:
     def digest(self) -> str:
         return hashlib.sha256(self.to_json().encode()).hexdigest()
 
+    def repinned(self, service, manager: str,
+                 envs: Mapping[str, str]) -> "Lockfile":
+        """This lock with the named ``manager`` components pinned to other
+        environment variants of the same version — e.g. the reference
+        kernels a platform build is checked against."""
+        pins, digests = [], []
+        for (m, n, v, e), dg in zip(self.pins, self.digests):
+            if m == manager and n in envs:
+                e = envs[n]
+                dg = service.cq(m, n, v, e).digest()
+            pins.append((m, n, v, e))
+            digests.append(dg)
+        return dataclasses.replace(self, pins=tuple(pins),
+                                   digests=tuple(digests))
+
 
 # ---------------------------------------------------------------------------
 # Build-plan cache (deployment-service hot path)

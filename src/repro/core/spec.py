@@ -59,6 +59,9 @@ GPU_A100 = ChipSpec(
 
 CHIPS = {c.name: c for c in (TPU_V5E, CPU_HOST, GPU_A100)}
 
+# jax ``device_kind`` strings of the TPUs described above
+TPU_KINDS = {"TPU v5e": TPU_V5E, "TPU v5 lite": TPU_V5E}
+
 
 # ---------------------------------------------------------------------------
 # SpecSheet
@@ -142,17 +145,25 @@ def probe_host(platform_id: str = "local",
                mesh_axes: Tuple[str, ...] = ("data",),
                chip: Optional[ChipSpec] = None) -> SpecSheet:
     """Inspect the *actual* host (paper: 'inspects the target hardware and
-    driver configuration').  Used for smoke tests and CPU execution."""
+    driver configuration').
+
+    A TPU is identified by its ``device_kind``; a kind or a backend with no
+    ``ChipSpec`` here raises rather than borrowing another chip's peaks."""
     import jax  # local import: keep module import free of jax side effects
 
     backend = jax.default_backend()
     if chip is None:
         if backend == "tpu":
-            chip = TPU_V5E
+            kind = jax.devices()[0].device_kind
+            if kind not in TPU_KINDS:
+                raise ValueError(f"no ChipSpec for TPU device kind {kind!r}")
+            chip = TPU_KINDS[kind]
         elif backend in ("gpu", "cuda", "rocm"):
             chip = GPU_A100
-        else:
+        elif backend == "cpu":
             chip = CPU_HOST
+        else:
+            raise ValueError(f"no ChipSpec for jax backend {backend!r}")
     return SpecSheet(
         platform_id=platform_id,
         chip=chip,

@@ -1,8 +1,7 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
 <name>.py   — pl.pallas_call + BlockSpec kernel (TPU target)
-ops.py      — jit'd wrappers matching the model-layer kernel interfaces
+ops.py      — wrappers matching the model-layer kernel interfaces
 ref.py      — pure-jnp oracles the tests assert against
 """
-from .ops import (pallas_attention, pallas_rmsnorm, pallas_wkv6,  # noqa: F401
-                  set_interpret)
+from .ops import pallas_attention, pallas_rmsnorm, pallas_wkv6  # noqa: F401

@@ -92,7 +92,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, scale: float, causal: bool = True,
                     window: int = 0, softcap: float = 0.0,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (b, hq, sq, d); k/v: (b, hkv, skv, d).  Returns (b, hq, sq, d)."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -126,5 +126,6 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),       # running denom l
             pltpu.VMEM((bq, dv), jnp.float32),    # output accumulator
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -1,52 +1,65 @@
-"""jit'd wrappers adapting the Pallas kernels to the model-layer interfaces.
+"""Wrappers adapting the Pallas kernels to the model-layer interfaces.
 
 These are the payloads of the ``kernel/*`` uniform components with
-``env='tpu-pallas'`` / ``env='cpu-interpret'``: the lazy-builder's
-environment selection decides whether the model's ATTN_KERNELS /
-WKV_IMPLS slots point here (Pallas) or to the lax/jnp variants.
+``env='tpu-pallas'`` / ``env='pallas-interpret'``.  The catalog binds
+``interpret`` from the build's ``SpecSheet.interpret_kernels`` and hands the
+bound callable to the model through ``Variants``, so every build carries its
+own mode: a cpu and a tpu instance built in one process do not share it.
 
-On a backend without a TPU, ``interpret=True`` executes the kernel body in
-Python via the Pallas interpreter — bit-accurate for correctness tests,
-useless for speed; that asymmetry is exactly the deployability trade-off
-Algorithm 1 scores.
+With ``interpret=True`` the kernel body runs in Python via the Pallas
+interpreter — bit-accurate for correctness tests, useless for speed; that
+asymmetry is exactly the deployability trade-off Algorithm 1 scores.
+
+Under an active sharding plan on more than one device, each kernel runs per
+shard (``shard_map`` over the plan's batch and head axes): a ``pallas_call``
+cannot be partitioned automatically.
 """
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
+from ..models.sharding import current_plan
 from .flash_attention import flash_attention
+from .rmsnorm import rmsnorm_pallas
 from .rwkv6_scan import wkv6_pallas
 
-_INTERPRET = True   # flipped by the catalog when specSheet.backend == 'tpu'
+_HEADS = ("act_batch", "act_heads", None, None)     # (b, h, s, d) layout
 
 
-def set_interpret(value: bool) -> None:
-    global _INTERPRET
-    _INTERPRET = bool(value)
+def _per_shard(fn: Callable, args: Sequence[jax.Array],
+               in_specs: Sequence[PartitionSpec], out_specs):
+    """Run ``fn`` on each device's shard of ``args`` under the active plan."""
+    plan = current_plan()
+    if plan is None or plan.mesh.size == 1:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=plan.mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
-def _pad_to(x, axis: int, mult: int):
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if not pad:
-        return x, 0
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths), pad
+def _heads_spec(x) -> PartitionSpec:
+    plan = current_plan()
+    if plan is None:
+        return PartitionSpec(None, None, None, None)
+    return plan.spec(_HEADS, x.shape)
 
 
 def pallas_attention(q, k, v, *, scale, causal=True, window=0, softcap=0.0,
-                     q_offset=0, kv_len=None, block_q=512, block_k=512):
-    """ATTN_KERNELS-compatible wrapper around the Pallas flash kernel.
+                     q_offset=0, kv_len=None, interpret: bool,
+                     block_q=512, block_k=512):
+    """Attention-kernel interface over the Pallas flash kernel.
 
-    Falls back to the blocked-lax path for ragged decode shapes (q_offset /
-    kv_len), which the train/prefill kernel does not model.
+    A prefill chunk that continues a partly filled cache (``q_offset`` /
+    ``kv_len``) takes the blocked-lax path: the kernel models a chunk that
+    attends only to itself.
     """
-    if q_offset != 0 or kv_len is not None:
-        from ..models.attention import lax_flash_attention
+    from ..models.attention import lax_flash_attention, naive_attention
+    fresh = kv_len is None and isinstance(q_offset, int) and q_offset == 0
+    if not fresh:
         return lax_flash_attention(q, k, v, scale=scale, causal=causal,
                                    window=window, softcap=softcap,
                                    q_offset=q_offset, kv_len=kv_len)
@@ -54,26 +67,59 @@ def pallas_attention(q, k, v, *, scale, causal=True, window=0, softcap=0.0,
     bq = min(block_q, sq)
     bk = min(block_k, skv)
     if sq % bq or skv % bk:
-        from ..models.attention import naive_attention
+        if not interpret:
+            raise ValueError(
+                f"flash attention needs lengths that are multiples of its "
+                f"blocks: q {sq} / {bq}, kv {skv} / {bk}")
         return naive_attention(q, k, v, scale=scale, causal=causal,
                                window=window, softcap=softcap)
-    return flash_attention(q, k, v, scale=scale, causal=causal,
-                           window=window, softcap=softcap,
-                           block_q=bq, block_k=bk, interpret=_INTERPRET)
+    fn = functools.partial(flash_attention, scale=scale, causal=causal,
+                           window=window, softcap=softcap, block_q=bq,
+                           block_k=bk, interpret=interpret)
+    # the kv-head spec shards q too: a q-head shard must hold the q heads
+    # of exactly the kv heads in its kv shard
+    spec = _heads_spec(k)
+    return _per_shard(fn, (q, k, v), (spec, spec, spec), spec)
 
 
-def pallas_wkv6(r, k, v, w, u, state=None, chunk: int = 64):
-    """WKV_IMPLS-compatible wrapper; sequential fallback for odd lengths."""
-    s = r.shape[2]
-    if s % min(chunk, s):
-        from ..models.ssm import wkv6_sequential
-        return wkv6_sequential(r, k, v, w, u, state)
-    y, s_out = wkv6_pallas(r, k, v, w, u, state,
-                           chunk=min(chunk, s), interpret=_INTERPRET)
-    return y, s_out
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def pallas_rmsnorm(x, w, eps: float = 1e-6, plus_one: bool = False):
-    from .rmsnorm import rmsnorm_pallas
-    return rmsnorm_pallas(x, w, eps=eps, plus_one=plus_one,
-                          interpret=_INTERPRET)
+def pallas_wkv6(r, k, v, w, u, state=None, *, interpret: bool,
+                chunk: int = 64):
+    """WKV-impl interface over the Pallas WKV6 kernel.
+
+    Any length runs the kernel: the tail is padded to the chunk with
+    ``r = k = v = 0`` and ``w = 1``, which leaves the output of the real
+    tokens and the carried state exact.  Short inputs (decode) use one
+    chunk of 16, the bf16 sublane tile.
+    """
+    b, h, s, K = r.shape
+    V = v.shape[-1]
+    L = chunk if s >= chunk else _round_up(s, 16)
+    pad = _round_up(s, L) - s
+    if pad:
+        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
+        r, k, v = (jnp.pad(t, widths) for t in (r, k, v))
+        w = jnp.pad(w, widths, constant_values=1)
+    if state is None:
+        state = jnp.zeros((b, h, K, V), jnp.float32)
+    fn = functools.partial(wkv6_pallas, chunk=L, interpret=interpret)
+    spec = _heads_spec(r)
+    u_spec = PartitionSpec(spec[1], None)
+    y, s_out = _per_shard(fn, (r, k, v, w, u, state),
+                          (spec, spec, spec, spec, u_spec, spec),
+                          (spec, spec))
+    return y[:, :, :s], s_out
+
+
+def pallas_rmsnorm(x, w, eps: float = 1e-6, plus_one: bool = False, *,
+                   interpret: bool):
+    """rms_norm interface over the fused Pallas kernel; x: (b, s, d)."""
+    fn = functools.partial(rmsnorm_pallas, eps=eps, plus_one=plus_one,
+                           interpret=interpret)
+    plan = current_plan()
+    spec = (plan.spec(("act_batch", "act_seq", None), x.shape)
+            if plan is not None else None)
+    return _per_shard(fn, (x, w), (spec, PartitionSpec()), spec)

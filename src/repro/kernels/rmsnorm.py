@@ -26,7 +26,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float, plus_one: bool):
 @functools.partial(
     jax.jit, static_argnames=("eps", "plus_one", "block_rows", "interpret"))
 def rmsnorm_pallas(x, w, *, eps: float = 1e-6, plus_one: bool = False,
-                   block_rows: int = 256, interpret: bool = True):
+                   block_rows: int = 256, interpret: bool):
     """x: (..., d); w: (d,)."""
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -50,6 +50,7 @@ def rmsnorm_pallas(x, w, *, eps: float = 1e-6, plus_one: bool = False,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x2, w)
     if pad:

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture × input-shape × mesh) cell:
@@ -20,6 +17,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -268,6 +266,9 @@ def main(argv=None) -> int:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    # 512 virtual host devices back the production meshes; the flag must
+    # be set before the first call initialises a jax backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     cells = []
     if args.all:

@@ -63,12 +63,9 @@ _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalized ``compiled.cost_analysis()``: jax <= 0.4.37 returns one
-    dict per device, newer jax a single dict.  Always returns a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()``, or an empty dict where the backend
+    reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def shape_bytes(type_str: str) -> int:

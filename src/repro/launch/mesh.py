@@ -12,14 +12,18 @@ from typing import Dict, List, Optional, Tuple
 
 
 def _make_mesh(shape, axes):
-    # AxisType landed in jax 0.4.38+; older jax defaults every axis to Auto
-    # already, so omitting axis_types is equivalent there.
+    """A mesh over the first ``prod(shape)`` local devices."""
     import jax
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    from jax.sharding import AxisType
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def parse_mesh(text: str) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:
+    """``'4'`` → ((4,), ('data',)); ``'1x4'`` → ((1, 4), ('data', 'model'))."""
+    shape = tuple(int(n) for n in text.lower().split("x"))
+    if not 1 <= len(shape) <= 2 or min(shape) < 1:
+        raise ValueError(f"mesh shape {text!r} is not N or DxM")
+    return shape, ("data", "model")[:len(shape)]
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -18,6 +18,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Mapping
 
 import jax
 import numpy as np
@@ -27,7 +28,45 @@ from ..core import (CompileCache, InstanceSnapshot, LazyBuilder, PreBuilder,
                     SPEC_LEASE_PREFIX, probe_host, restore_instance,
                     snapshot_instance, write_sbom)
 from ..core import catalog
-from .mesh import make_smoke_mesh
+from .jax_cache import enable_compile_cache
+from .mesh import _make_mesh, parse_mesh
+
+# the plain variants a platform build's kernels are checked against
+REFERENCE_KERNELS = {"attention": "naive", "wkv6": "sequential",
+                     "rmsnorm": "xla"}
+
+
+def build_serving(builder: LazyBuilder, cfg, mesh_shape=(1,),
+                  mesh_axes=("data",), *, compile_steps: bool = False):
+    """PreBuilder → ``LazyBuilder.build(probe_host(...))`` of a serving CIR
+    for a mesh over the local devices.  Non-blocking: callers wait on the
+    instance's lifecycle stages."""
+    cir = PreBuilder(builder.service).prebuild(cfg, entrypoint="serve")
+    spec = probe_host(mesh_shape=tuple(mesh_shape),
+                      mesh_axes=tuple(mesh_axes))
+    # the orchestrator overlaps assemble/compile with the weight-asset tail
+    return builder.build(cir, spec, mesh=_make_mesh(mesh_shape, mesh_axes),
+                         overrides={"workload": "decode"},
+                         compile_steps=compile_steps, block=False)
+
+
+def rebuild_with_kernels(builder: LazyBuilder, inst,
+                         kernels: Mapping[str, str]):
+    """``inst`` rebuilt from its own lock with the kernel variants named in
+    ``kernels`` (kernel name → env, e.g. :data:`REFERENCE_KERNELS`) in place
+    of the ones resolution picked: same CIR, platform and plan."""
+    lock = inst.lock.repinned(builder.service, "kernel", kernels)
+    return builder.build_from_lock(inst.cir, lock, inst.spec,
+                                   mesh=inst.entry["plan"].mesh)
+
+
+def init_params(inst, seed: int):
+    """Random parameters from ``seed``, created by one jitted init straight
+    into the serve plan's parameter shardings: no device ever holds more
+    of the model than the plan gives it."""
+    init = jax.jit(inst.model.init,
+                   out_shardings=inst.entry["param_shardings"]())
+    return init(jax.random.PRNGKey(seed))
 
 
 def main(argv=None) -> int:
@@ -40,6 +79,10 @@ def main(argv=None) -> int:
     ap.add_argument("--max-seq", type=int, default=256)
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--mesh", default="1", type=parse_mesh,
+                    help="local device mesh: N (data) or DxM (data x "
+                         "model); feeds both probe_host and the mesh")
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--snapshot-out", metavar="PATH", default=None,
                     help="write an ASSEMBLED+COMPILED instance snapshot "
                          "once READY (restorable via --restore)")
@@ -65,6 +108,9 @@ def main(argv=None) -> int:
         ap.error("--retire-spec requires --snapshot-out (retiring without "
                  "a snapshot would strand the instance)")
 
+    print("compile cache:", enable_compile_cache())
+    mesh_shape, mesh_axes = args.mesh
+
     svc = catalog.default_service()
     builder = LazyBuilder(svc, compile_cache=CompileCache(),
                           ir_components=args.platform_report)
@@ -72,23 +118,18 @@ def main(argv=None) -> int:
     if args.restore:
         with open(args.restore) as f:
             snap = InstanceSnapshot.from_json(f.read())
-        inst = restore_instance(snap, builder, mesh=make_smoke_mesh(1),
+        inst = restore_instance(snap, builder,
+                                mesh=_make_mesh(mesh_shape, mesh_axes),
                                 block=False)
-        cir, cfg = inst.cir, inst.cir.arch_config()
+        cfg = inst.cir.arch_config()
     else:
         cfg = ARCHS[args.arch]
         if not args.full:
             cfg = cfg.reduced()
-        cir = PreBuilder(svc).prebuild(cfg, entrypoint="serve")
-        spec = probe_host(mesh_shape=(1,), mesh_axes=("data",))
-        # non-blocking lazy-build: the orchestrator overlaps
-        # assemble/compile with the weight-asset tail; we wait on
-        # lifecycle stages, not build()
-        inst = builder.build(cir, spec, mesh=make_smoke_mesh(1),
-                             overrides={"workload": "decode"},
+        inst = build_serving(builder, cfg, mesh_shape, mesh_axes,
                              compile_steps=bool(args.snapshot_out
-                                                or args.platform_report),
-                             block=False)
+                                                or args.platform_report))
+    cir = inst.cir
     inst.wait("ready")
     verb = "restored" if args.restore else "lazy-built"
     print(f"{verb} {cir.name} for {inst.spec.platform_id}; "
@@ -137,12 +178,12 @@ def main(argv=None) -> int:
             print("instance content demoted to the speculative eviction "
                   "tier (evictable first; restore promotes it back)")
 
-    params = inst.model.init(jax.random.PRNGKey(0))
+    params = init_params(inst, args.seed)
     engine = inst.entry["make_engine"](
         params, num_slots=args.slots, max_seq=args.max_seq,
         prefill_buckets=(32,))
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     t0 = time.perf_counter()
     for i in range(args.requests):
         ln = int(rng.integers(4, 24))

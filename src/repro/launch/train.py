@@ -23,6 +23,7 @@ from ..configs import ARCHS
 from ..core import LazyBuilder, PreBuilder, probe_host
 from ..core import catalog
 from ..runtime import RuntimeConfig, TrainDriver
+from .jax_cache import enable_compile_cache
 from .mesh import make_smoke_mesh
 
 
@@ -40,6 +41,7 @@ def main(argv=None) -> int:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    print("compile cache:", enable_compile_cache())
 
     cfg = ARCHS[args.arch]
     if not args.full:

@@ -1,16 +1,17 @@
 """Attention: GQA (naive / chunked-flash / pallas), MLA, sliding window,
 softcap, M-RoPE; training and decode (KV cache) paths.
 
-The *kernel* actually used is a uniform component (kernel/flash-attention)
-selected by the lazy-builder: ``naive`` for tiny smoke shapes, ``lax-flash``
-(chunked online-softmax, VMEM-bounded) for compiled CPU/dry-run targets, and
-the Pallas TPU kernel when the specSheet says a real TPU is present.
+The *kernel* actually used is a uniform component (kernel/attention)
+selected by the lazy-builder and passed in as a callable: ``naive`` for tiny
+smoke shapes, ``lax-flash`` (chunked online-softmax, VMEM-bounded) for
+compiled CPU/dry-run targets, and the Pallas TPU kernel when the specSheet
+says a real TPU is present.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Mapping, Optional, Tuple
+from typing import Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -137,16 +138,6 @@ def lax_flash_attention(q, k, v, *, scale, causal=True, window=0,
     return o.astype(v.dtype)
 
 
-ATTN_KERNELS: Dict[str, Any] = {
-    "naive": naive_attention,
-    "lax-flash": lax_flash_attention,
-}
-
-
-def register_attention_kernel(name: str, fn) -> None:
-    ATTN_KERNELS[name] = fn
-
-
 # ---------------------------------------------------------------------------
 # GQA module
 # ---------------------------------------------------------------------------
@@ -173,11 +164,13 @@ def _proj(x, w, b=None):
     return y
 
 
-def gqa_attention(params, x, cfg, *, positions, kernel="lax-flash",
+def gqa_attention(params, x, cfg, *, positions, kernel=lax_flash_attention,
                   window=0, cache=None, cache_pos=None,
                   query_scale: Optional[float] = None):
-    """Returns (out, new_cache).  Train: cache=None.  Decode: cache is
-    {'k': (b, kv, S, hd), 'v': ...} updated at cache_pos (int32 scalar)."""
+    """Returns (out, new_cache).  ``kernel`` is the selected attention
+    callable.  Train: cache=None.  Decode: cache is {'k': (b, kv, S, hd),
+    'v': ...} updated at cache_pos (int32 scalar; the Python int 0 marks a
+    fresh prefill)."""
     b, s, dm = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
     q = _proj(x, params["wq"], params.get("bq")).reshape(b, s, h, hd)
@@ -195,11 +188,10 @@ def gqa_attention(params, x, cfg, *, positions, kernel="lax-flash",
     q = shard(q, "act_batch", "act_heads", "act_seq", None)
 
     scale = query_scale if query_scale is not None else 1.0 / math.sqrt(hd)
-    fn = ATTN_KERNELS[kernel]
     new_cache = None
     if cache is None:
-        o = fn(q, k, v, scale=scale, causal=True, window=window,
-               softcap=cfg.attn_softcap)
+        o = kernel(q, k, v, scale=scale, causal=True, window=window,
+                   softcap=cfg.attn_softcap)
     else:
         cache_len = cache["k"].shape[2]
         ring = bool(window) and cache_len <= window
@@ -232,8 +224,8 @@ def gqa_attention(params, x, cfg, *, positions, kernel="lax-flash",
                 # tokens (requires s % window == 0 or s <= window so slot
                 # layout stays aligned)
                 assert s % window == 0 or s < window, (s, window)
-                o = fn(q, k, v, scale=scale, causal=True, window=window,
-                       softcap=cfg.attn_softcap)
+                o = kernel(q, k, v, scale=scale, causal=True,
+                           window=window, softcap=cfg.attn_softcap)
                 if s >= window:
                     ck = k[:, :, -window:, :].astype(cache["k"].dtype)
                     cv = v[:, :, -window:, :].astype(cache["v"].dtype)
@@ -262,10 +254,15 @@ def gqa_attention(params, x, cfg, *, positions, kernel="lax-flash",
                 o = naive_attention(q, ck, cv, scale=scale, causal=False,
                                     window=window, softcap=cfg.attn_softcap,
                                     q_offset=cache_pos, kv_len=cache_pos + 1)
-            else:        # prefill chunk: causal within the chunk
-                o = fn(q, ck, cv, scale=scale, causal=True, window=window,
-                       softcap=cfg.attn_softcap, q_offset=cache_pos,
-                       kv_len=cache_pos + s)
+            elif isinstance(cache_pos, int) and cache_pos == 0:
+                # fresh prefill: the cache holds nothing before this chunk,
+                # so the chunk attends only to itself
+                o = kernel(q, k, v, scale=scale, causal=True,
+                           window=window, softcap=cfg.attn_softcap)
+            else:        # prefill chunk continuing a partly filled cache
+                o = kernel(q, ck, cv, scale=scale, causal=True,
+                           window=window, softcap=cfg.attn_softcap,
+                           q_offset=cache_pos, kv_len=cache_pos + s)
     o = jnp.swapaxes(o, 1, 2).reshape(b, s, h * hd)
     out = jnp.einsum("bsf,fd->bsd", o, params["wo"].astype(o.dtype))
     return shard(out, "act_batch", "act_seq", "act_embed"), new_cache
@@ -302,7 +299,7 @@ def _rms(x, w):
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) * w).astype(x.dtype)
 
 
-def mla_attention(params, x, cfg, *, positions, kernel="lax-flash",
+def mla_attention(params, x, cfg, *, positions, kernel=lax_flash_attention,
                   cache=None, cache_pos=None, **_):
     """Train path decompresses K/V per head and runs flash; decode path keeps
     the cache *compressed* (c_kv + k_rope) — the MLA memory saving — and
@@ -335,8 +332,7 @@ def mla_attention(params, x, cfg, *, positions, kernel="lax-flash",
         kf = jnp.swapaxes(k, 1, 2)
         vf = jnp.swapaxes(v, 1, 2)
         qf = shard(qf, "act_batch", "act_heads", "act_seq", None)
-        fn = ATTN_KERNELS[kernel]
-        o = fn(qf, kf, vf, scale=scale, causal=True)
+        o = kernel(qf, kf, vf, scale=scale, causal=True)
         o = jnp.swapaxes(o, 1, 2)
         new_cache = None
     else:

@@ -117,10 +117,10 @@ def norm_spec(cfg, d: Optional[int] = None) -> SpecTree:
             "b": P((d,), ("embed",), "zeros")}
 
 
-def apply_norm(params, x, cfg):
+def apply_norm(params, x, cfg, rms=rms_norm):
+    """``rms`` is the selected RMSNorm kernel (``Variants.rms_norm``)."""
     if cfg.norm == "rms":
-        return rms_norm(x, params["w"],
-                        plus_one=cfg.arch_id.startswith("gemma"))
+        return rms(x, params["w"], plus_one=cfg.arch_id.startswith("gemma"))
     return layer_norm(x, params["w"], params["b"])
 
 

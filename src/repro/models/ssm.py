@@ -92,13 +92,6 @@ def wkv6_chunked(r, k, v, w, u, state=None, chunk: int = 32):
     return y.astype(v.dtype), S
 
 
-WKV_IMPLS = {"sequential": wkv6_sequential, "chunked": wkv6_chunked}
-
-
-def register_wkv_impl(name, fn):
-    WKV_IMPLS[name] = fn
-
-
 # ---------------------------------------------------------------------------
 # RWKV6 block (time-mix + channel-mix)
 # ---------------------------------------------------------------------------
@@ -145,9 +138,10 @@ def _token_shift(x, prev):
     return shifted
 
 
-def rwkv6_time_mix(p, x, cfg, state=None, wkv_impl="chunked"):
+def rwkv6_time_mix(p, x, cfg, state=None, wkv_impl=wkv6_chunked):
     """x: (b, s, d).  state: None (train, zero init) or dict with
-    'shift' (b, d) and 'wkv' (b, h, K, V)."""
+    'shift' (b, d) and 'wkv' (b, h, K, V).  ``wkv_impl`` is the selected
+    WKV6 callable."""
     b, s, d = x.shape
     hs = cfg.rwkv_head_size
     h = d // hs
@@ -182,8 +176,7 @@ def rwkv6_time_mix(p, x, cfg, state=None, wkv_impl="chunked"):
     rh = shard(rh, "act_batch", "act_heads", "act_seq", None)
 
     wkv_state = state["wkv"] if state is not None else None
-    fn = WKV_IMPLS[wkv_impl]
-    y, S = fn(rh, kh, vh, wh, p["faaaa"], wkv_state)
+    y, S = wkv_impl(rh, kh, vh, wh, p["faaaa"], wkv_state)
     y = jnp.swapaxes(y, 1, 2).reshape(b, s, d)
 
     # per-head group norm

@@ -22,23 +22,26 @@ import jax
 import jax.numpy as jnp
 
 from .attention import (gqa_attention, gqa_cache_spec, gqa_spec,
-                        mla_attention, mla_cache_spec, mla_spec)
+                        lax_flash_attention, mla_attention, mla_cache_spec,
+                        mla_spec)
 from .common import (P, SpecTree, apply_norm, axes_tree, cross_entropy,
                      embed_spec, embed_tokens, eval_shape_tree, head_spec,
-                     init_tree, lm_logits, norm_spec, sinusoidal_pos, softcap,
-                     stacked)
+                     init_tree, lm_logits, norm_spec, rms_norm,
+                     sinusoidal_pos, softcap, stacked)
 from .ffn import MOE_IMPLS, ffn_apply, ffn_spec, moe_spec
 from .sharding import shard
 from .ssm import (mamba_block, mamba_spec, mamba_state_spec,
                   rwkv6_channel_mix, rwkv6_spec, rwkv6_state_spec,
-                  rwkv6_time_mix)
+                  rwkv6_time_mix, wkv6_chunked)
 
 
 @dataclasses.dataclass
 class Variants:
-    attn_kernel: str = "lax-flash"
+    """The kernel callables and policies one build selected."""
+    attn_kernel: Callable = lax_flash_attention
     moe_impl: str = "grouped"
-    wkv_impl: str = "chunked"
+    wkv_impl: Callable = wkv6_chunked
+    rms_norm: Callable = rms_norm
     remat: str = "full"            # none | full | dots
     capacity_factor: float = 1.25
     moe_combine: str = "f32"       # f32 | bf16 slot tensors / combine
@@ -124,7 +127,8 @@ class Model:
     def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0):
         h, new_cache, aux = self.backbone(params, embeds, positions,
                                           cache, cache_pos)
-        h = apply_norm(params["final_norm"], h, self.cfg)
+        h = apply_norm(params["final_norm"], h, self.cfg,
+                       self.variants.rms_norm)
         logits = lm_logits(params.get("head", {}), params["embed"], h,
                            self.cfg)
         logits = shard(logits, "act_batch", "act_seq", "act_vocab")
@@ -167,15 +171,16 @@ class Model:
         token t+2 from (norm(h_t), norm(emb_{t+1}))."""
         cfg = self.cfg
         p = params["mtp"]
-        h_in = apply_norm(p["norm_h"], h, cfg)
+        h_in = apply_norm(p["norm_h"], h, cfg, self.variants.rms_norm)
         e_next = jnp.roll(e, -1, axis=1)
-        e_in = apply_norm(p["norm_e"], e_next, cfg)
+        e_in = apply_norm(p["norm_e"], e_next, cfg, self.variants.rms_norm)
         x = jnp.einsum("bsd,de->bse",
                        jnp.concatenate([h_in, e_in], -1),
                        p["proj"].astype(h.dtype))
         positions = batch["positions"]
         x, _, _ = self._mtp_block_apply(p["block"], x, positions)
-        x = apply_norm(params["final_norm"], x, cfg)
+        x = apply_norm(params["final_norm"], x, cfg,
+                       self.variants.rms_norm)
         logits = lm_logits(params.get("head", {}), params["embed"], x, cfg)
         labels2 = jnp.roll(batch["labels"], -1, axis=1)
         mask = jnp.ones_like(labels2, jnp.float32).at[:, -2:].set(0.0)
@@ -192,7 +197,8 @@ class Model:
         e = self.embed(params, batch)
         positions = batch["positions"]
         h, cache, _ = self.backbone(params, e, positions, cache, 0)
-        h_last = apply_norm(params["final_norm"], h[:, -1:, :], self.cfg)
+        h_last = apply_norm(params["final_norm"], h[:, -1:, :], self.cfg,
+                            self.variants.rms_norm)
         logits = lm_logits(params.get("head", {}), params["embed"], h_last,
                            self.cfg)
         return logits[:, 0, :], cache
@@ -246,21 +252,21 @@ def _make_attn_ffn_block(cfg, v: Variants, *, moe: bool, window: int):
         qscale = (cfg.d_model / cfg.n_heads) ** -0.5   # query_pre_attn_scalar
 
     def apply(p, x, positions, cache, cache_pos):
-        h = apply_norm(p["norm1"], x, cfg)
+        h = apply_norm(p["norm1"], x, cfg, v.rms_norm)
         a, new_cache = attn_fn(p["attn"], h, cfg, positions=positions,
                                kernel=v.attn_kernel, window=window,
                                cache=cache, cache_pos=cache_pos,
                                query_scale=qscale)
         if cfg.post_norms:
-            a = apply_norm(p["post1"], a, cfg)
+            a = apply_norm(p["post1"], a, cfg, v.rms_norm)
         x = x + a
-        h = apply_norm(p["norm2"], x, cfg)
+        h = apply_norm(p["norm2"], x, cfg, v.rms_norm)
         if moe:
             f, aux = moe_fn(p["ffn"], h, cfg)
         else:
             f, aux = ffn_apply(p["ffn"], h, cfg), jnp.zeros((), jnp.float32)
         if cfg.post_norms:
-            f = apply_norm(p["post2"], f, cfg)
+            f = apply_norm(p["post2"], f, cfg, v.rms_norm)
         x = x + f
         return x, new_cache, aux
 
@@ -338,10 +344,10 @@ def _rwkv_stacks(cfg, v: Variants) -> Tuple[Stack, ...]:
         tm_state = None
         if cache is not None:
             tm_state = {"shift": cache["tm_shift"], "wkv": cache["wkv"]}
-        h = apply_norm(p["norm1"], x, cfg)
+        h = apply_norm(p["norm1"], x, cfg, v.rms_norm)
         a, tm_new = rwkv6_time_mix(p["tm"], h, cfg, tm_state, v.wkv_impl)
         x = x + a
-        h = apply_norm(p["norm2"], x, cfg)
+        h = apply_norm(p["norm2"], x, cfg, v.rms_norm)
         cm_state = cache["cm_shift"] if cache is not None else None
         f, cm_new = rwkv6_channel_mix(p["cm"], h, cfg, cm_state)
         x = x + f
@@ -385,7 +391,7 @@ def _hybrid_stacks(cfg, v: Variants) -> Tuple[Stack, ...]:
             is_attn = (i == cfg.attn_offset)
             is_moe = cfg.is_moe and (i % cfg.moe_every == 1)
             ci = cache.get(f"l{i}") if cache is not None else None
-            h = apply_norm(sp["norm1"], x, cfg)
+            h = apply_norm(sp["norm1"], x, cfg, v.rms_norm)
             if is_attn:
                 a, c_new = gqa_attention(sp["mix"], h, cfg,
                                          positions=positions,
@@ -396,7 +402,7 @@ def _hybrid_stacks(cfg, v: Variants) -> Tuple[Stack, ...]:
                 if cache is None:
                     c_new = None
             x = x + a
-            h = apply_norm(sp["norm2"], x, cfg)
+            h = apply_norm(sp["norm2"], x, cfg, v.rms_norm)
             if is_moe:
                 f, a_l = moe_fn(sp["ffn"], h, cfg)
                 aux = aux + a_l

@@ -10,6 +10,10 @@ The engine owns ``num_slots`` cache slots.  Each engine tick:
 
 Everything jitted is shape-stable: (num_slots, 1) decode, a fixed set of
 prefill buckets — no recompiles in steady state.
+
+Given the serve entry's sharding ``plan``, the cache is created straight
+into the plan's cache shardings and every jitted body traces under the plan,
+so a model spread over several chips stays spread.
 """
 from __future__ import annotations
 
@@ -21,6 +25,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .models.common import P
+from .models.sharding import use_plan
 
 
 @dataclasses.dataclass
@@ -46,7 +53,7 @@ class ServingEngine:
     def __init__(self, model, params, *, num_slots: int = 8,
                  max_seq: int = 1024,
                  prefill_buckets: Sequence[int] = (64, 256),
-                 eos_id: int = -1, rng_seed: int = 0):
+                 eos_id: int = -1, rng_seed: int = 0, plan=None):
         self.model = model
         self.params = params
         self.num_slots = num_slots
@@ -54,8 +61,19 @@ class ServingEngine:
         self.prefill_buckets = sorted(prefill_buckets)
         self.eos_id = eos_id
         self.cfg = model.cfg
+        self.plan = plan
 
-        self.cache = model.init_cache(num_slots, max_seq)
+        def cache_shardings(batch: int):
+            if plan is None:
+                return None
+            return jax.tree.map(lambda p: plan.sharding(p.axes, p.shape),
+                                model.cache_specs(batch, max_seq),
+                                is_leaf=lambda x: isinstance(x, P))
+
+        cache_sh = cache_shardings(num_slots)
+        self.cache = jax.jit(
+            lambda: model.init_cache(num_slots, max_seq),
+            out_shardings=cache_sh)()
         self.queue: deque[Request] = deque()
         self.slot_req: List[Optional[Request]] = [None] * num_slots
         self.slot_pos = np.zeros(num_slots, np.int32)       # next write pos
@@ -67,15 +85,25 @@ class ServingEngine:
         self._key = jax.random.PRNGKey(rng_seed)
         self._ticks = 0
 
-        # jitted single-slot prefill (per bucket) and fused decode
+        # jitted single-slot prefill (per bucket), slot insert and fused
+        # decode; the cache keeps the plan's shardings across all three
         self._prefill = jax.jit(self._prefill_impl,
-                                static_argnames=("bucket",))
-        self._decode = jax.jit(self._decode_impl)
+                                static_argnames=("bucket",),
+                                out_shardings=(None, cache_shardings(1)))
+        self._insert = jax.jit(self._insert_impl, out_shardings=cache_sh,
+                               donate_argnums=(0,))
+        self._decode = jax.jit(self._decode_impl,
+                               out_shardings=(None, cache_sh),
+                               donate_argnums=(1,))
 
     # -- jitted bodies ------------------------------------------------------
     def _prefill_impl(self, params, tokens, length, bucket: int):
         """tokens: (1, bucket); length: scalar prompt length.
         Returns (next_token_logits (1, v), cache_b1)."""
+        with use_plan(self.plan):
+            return self._prefill_body(params, tokens, length, bucket)
+
+    def _prefill_body(self, params, tokens, length, bucket: int):
         m = self.model
         cache = m.init_cache(1, self.max_seq)
         pos = jnp.arange(bucket, dtype=jnp.int32)[None]
@@ -98,9 +126,23 @@ class ServingEngine:
             if jnp.ndim(length) == 0 else length[:, None, None], axis=1)
         return last[:, 0, :], cache
 
+    @staticmethod
+    def _insert_impl(full_cache, cache1, slot):
+        """Scatter a prefilled batch-1 cache into ``slot`` (batch axis = 1,
+        because stacked cache leaves are (layers, batch, ...))."""
+        return jax.tree.map(
+            lambda full, one: jax.lax.dynamic_update_slice_in_dim(
+                full, one.astype(full.dtype), slot, axis=1),
+            full_cache, cache1)
+
     def _decode_impl(self, params, cache, tokens, positions, live, key,
                      temps):
         """tokens: (slots,); positions: (slots,); live: (slots,) bool."""
+        with use_plan(self.plan):
+            return self._decode_body(params, cache, tokens, positions, key,
+                                     temps)
+
+    def _decode_body(self, params, cache, tokens, positions, key, temps):
         m = self.model
         toks = tokens[:, None]
         pos = positions[:, None]
@@ -142,28 +184,49 @@ class ServingEngine:
                 return b
         return self.prefill_buckets[-1]
 
+    def _prefill_args(self, prompt: Sequence[int]) -> Tuple[tuple, dict]:
+        """The (args, kwargs) the jitted prefill takes for ``prompt``: its
+        bucket-padded tokens and its length (clipped to the bucket)."""
+        n = len(prompt)
+        bucket = self._bucket_for(n)
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :n] = np.asarray(prompt, np.int32)[:bucket]
+        return ((self.params, jnp.asarray(toks),
+                 jnp.asarray(min(n, bucket), jnp.int32)),
+                {"bucket": bucket})
+
+    def prefill(self, prompt: Sequence[int]):
+        """Prefill ``prompt`` alone: (next-token logits (1, vocab), the
+        batch-1 cache, the number of prompt tokens kept)."""
+        args, kw = self._prefill_args(prompt)
+        logits, cache1 = self._prefill(*args, **kw)
+        return logits, cache1, int(args[2])
+
+    def lower_prefill(self, prompt: Sequence[int]) -> jax.stages.Lowered:
+        """The prefill program ``prompt`` runs, lowered (not run)."""
+        args, kw = self._prefill_args(prompt)
+        return self._prefill.lower(*args, **kw)
+
+    def lower_decode(self) -> jax.stages.Lowered:
+        """The fused decode step over all slots, lowered (not run)."""
+        n = self.num_slots
+        return self._decode.lower(
+            self.params, self.cache, jnp.zeros(n, jnp.int32),
+            jnp.zeros(n, jnp.int32), jnp.ones(n, bool), self._key,
+            jnp.zeros(n, jnp.float32))
+
     def _admit(self) -> None:
         for slot in range(self.num_slots):
             if self.slot_req[slot] is not None or not self.queue:
                 continue
             req = self.queue.popleft()
             t0 = time.perf_counter()
-            n = len(req.prompt)
-            bucket = self._bucket_for(n)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :n] = req.prompt[:bucket]
-            logits, cache1 = self._prefill(
-                self.params, jnp.asarray(toks),
-                jnp.asarray(min(n, bucket), jnp.int32), bucket=bucket)
-            # scatter the prefilled cache into this slot (batch axis = 1,
-            # because stacked cache leaves are (layers, batch, ...))
-            self.cache = jax.tree.map(
-                lambda full, one: jax.lax.dynamic_update_slice_in_dim(
-                    full, one.astype(full.dtype), slot, axis=1),
-                self.cache, cache1)
+            logits, cache1, n_kept = self.prefill(req.prompt)
+            self.cache = self._insert(self.cache, cache1,
+                                      jnp.asarray(slot, jnp.int32))
             first = int(jax.device_get(jnp.argmax(logits[0])))
             self.slot_req[slot] = req
-            self.slot_pos[slot] = min(n, bucket)
+            self.slot_pos[slot] = n_kept
             self.slot_out[slot] = [first]
             self._next_tokens[slot] = first
             self.slot_t0[slot] = req.submitted_at

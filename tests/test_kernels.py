@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import pallas_attention, pallas_wkv6, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.rwkv6_scan import wkv6_pallas
@@ -35,7 +35,8 @@ def test_flash_attention_vs_ref(b, hq, hkv, s, d, dtype):
     k = jax.random.normal(ks[1], (b, hkv, s, d), dtype)
     v = jax.random.normal(ks[2], (b, hkv, s, d), dtype)
     out = flash_attention(q, k, v, scale=d ** -0.5,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64,
+                          interpret=True)
     exp = ref.attention_ref(q, k, v, scale=d ** -0.5)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
@@ -49,7 +50,8 @@ def test_flash_attention_window_softcap(window, softcap):
     k = jax.random.normal(ks[1], (b, hkv, s, d))
     v = jax.random.normal(ks[2], (b, hkv, s, d))
     out = flash_attention(q, k, v, scale=0.2, window=window, softcap=softcap,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64,
+                          interpret=True)
     exp = ref.attention_ref(q, k, v, scale=0.2, window=window,
                             softcap=softcap)
     np.testing.assert_allclose(out, exp, atol=3e-5, rtol=3e-5)
@@ -63,10 +65,27 @@ def test_flash_attention_mla_asymmetric_vdim():
     k = jax.random.normal(ks[1], (b, h, s, 192))
     v = jax.random.normal(ks[2], (b, h, s, 128))
     out = flash_attention(q, k, v, scale=192 ** -0.5,
-                          block_q=64, block_k=64)
+                          block_q=64, block_k=64,
+                          interpret=True)
     exp = ref.attention_ref(q, k, v, scale=192 ** -0.5)
     assert out.shape == (b, h, s, 128)
     np.testing.assert_allclose(out, exp, atol=3e-5, rtol=3e-5)
+
+
+def test_pallas_attention_unaligned_length_raises_when_compiled():
+    """A length the flash blocks do not tile raises on the compiled path
+    instead of quietly running another kernel; interpret mode still
+    answers (through the naive oracle)."""
+    b, h, s, d = 1, 2, 80, 32
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k, v = (jax.random.normal(kk, (b, h, s, d)) for kk in ks)
+    with pytest.raises(ValueError, match="multiples of its blocks"):
+        pallas_attention(q, k, v, scale=0.3, block_q=64, block_k=64,
+                         interpret=False)
+    out = pallas_attention(q, k, v, scale=0.3, block_q=64, block_k=64,
+                           interpret=True)
+    np.testing.assert_allclose(out, ref.attention_ref(q, k, v, scale=0.3),
+                               atol=3e-5, rtol=3e-5)
 
 
 def test_lax_flash_matches_ref_and_naive():
@@ -98,7 +117,7 @@ def test_wkv6_pallas_vs_ref(b, h, s, K, chunk):
     v = jax.random.normal(ks[2], (b, h, s, K))
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
     u = jax.random.normal(ks[4], (h, K)) * 0.1
-    y, S = wkv6_pallas(r, k, v, w, u, chunk=chunk)
+    y, S = wkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=True)
     ye, Se = ref.wkv6_ref(r, k, v, w, u)
     np.testing.assert_allclose(y, ye, atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
@@ -113,8 +132,27 @@ def test_wkv6_pallas_with_initial_state():
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
     u = jax.random.normal(ks[4], (h, K)) * 0.1
     s0 = jax.random.normal(ks[5], (b, h, K, K), jnp.float32)
-    y, S = wkv6_pallas(r, k, v, w, u, s0, chunk=32)
+    y, S = wkv6_pallas(r, k, v, w, u, s0, chunk=32, interpret=True)
     ye, Se = ref.wkv6_ref(r, k, v, w, u, s0)
+    np.testing.assert_allclose(y, ye, atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("s", [1, 50, 100])
+def test_pallas_wkv6_pads_any_length_exactly(s):
+    """The wrapper pads the tail (r = k = v = 0, w = 1) instead of falling
+    back: output and carried state match the recurrence at any length."""
+    b, h, K = 1, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(9), 6)
+    r = jax.random.normal(ks[0], (b, h, s, K))
+    k = jax.random.normal(ks[1], (b, h, s, K))
+    v = jax.random.normal(ks[2], (b, h, s, K))
+    w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
+    u = jax.random.normal(ks[4], (h, K)) * 0.1
+    s0 = jax.random.normal(ks[5], (b, h, K, K), jnp.float32)
+    y, S = pallas_wkv6(r, k, v, w, u, s0, interpret=True)
+    ye, Se = wkv6_sequential(r, k, v, w, u, s0)
+    assert y.shape == (b, h, s, K)
     np.testing.assert_allclose(y, ye, atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
 
@@ -144,7 +182,8 @@ def test_wkv6_chunked_and_sequential_match_ref():
 def test_rmsnorm_pallas_vs_ref(shape, plus_one, dtype):
     x = jax.random.normal(jax.random.PRNGKey(7), shape, dtype)
     w = jax.random.normal(jax.random.PRNGKey(8), (shape[-1],), dtype)
-    out = rmsnorm_pallas(x, w, plus_one=plus_one, block_rows=16)
+    out = rmsnorm_pallas(x, w, plus_one=plus_one, block_rows=16,
+                         interpret=True)
     exp = ref.rmsnorm_ref(x, w, plus_one=plus_one)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
@@ -167,15 +206,15 @@ def test_gqa_decode_matches_train_attention():
     b, s = 2, 16
     x = jax.random.normal(jax.random.PRNGKey(1), (b, s + 1, cfg.d_model))
     pos = jnp.tile(jnp.arange(s + 1), (b, 1))
-    full, _ = gqa_attention(p, x, cfg, positions=pos, kernel="naive")
+    full, _ = gqa_attention(p, x, cfg, positions=pos, kernel=naive_attention)
 
     cache = init_tree(jax.random.PRNGKey(2),
                       gqa_cache_spec(cfg, b, 32))
     cache = jax.tree.map(jnp.zeros_like, cache)
     _, cache = gqa_attention(p, x[:, :s], cfg, positions=pos[:, :s],
-                             kernel="naive", cache=cache, cache_pos=0)
+                             kernel=naive_attention, cache=cache, cache_pos=0)
     out1, _ = gqa_attention(p, x[:, s:], cfg, positions=pos[:, s:],
-                            kernel="naive", cache=cache, cache_pos=s)
+                            kernel=naive_attention, cache=cache, cache_pos=s)
     np.testing.assert_allclose(out1[:, 0], full[:, s], atol=1e-4, rtol=1e-4)
 
 
@@ -205,10 +244,10 @@ def test_ring_buffer_window_decode_matches_full_cache():
         xt = x[:, t:t + 1]
         pt = pos[:, t:t + 1]
         o_full, full_cache = gqa_attention(
-            p, xt, cfg, positions=pt, kernel="naive", window=W,
+            p, xt, cfg, positions=pt, kernel=naive_attention, window=W,
             cache=full_cache, cache_pos=t)
         o_ring, ring_cache = gqa_attention(
-            p, xt, cfg, positions=pt, kernel="naive", window=W,
+            p, xt, cfg, positions=pt, kernel=naive_attention, window=W,
             cache=ring_cache, cache_pos=t)
         np.testing.assert_allclose(o_ring, o_full, atol=1e-4, rtol=1e-4,
                                    err_msg=f"step {t}")
@@ -227,7 +266,7 @@ def test_mla_decode_matches_train_path():
     x = jax.random.normal(jax.random.PRNGKey(1), (b, s + 1, cfg.d_model)) \
         * 0.3
     pos = jnp.tile(jnp.arange(s + 1), (b, 1))
-    full, _ = mla_attention(p, x, cfg, positions=pos, kernel="naive")
+    full, _ = mla_attention(p, x, cfg, positions=pos, kernel=naive_attention)
 
     cache = jax.tree.map(jnp.zeros_like, init_tree(
         jax.random.PRNGKey(2), mla_cache_spec(cfg, b, 32)))

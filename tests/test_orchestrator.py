@@ -330,5 +330,19 @@ def test_probe_host_maps_backends_to_chips(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     assert probe_host().chip is CPU_HOST
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    s = probe_host()
-    assert s.chip is TPU_V5E and not s.interpret_kernels
+
+    class _Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    for kind in ("TPU v5 lite", "TPU v5e"):
+        monkeypatch.setattr(jax, "devices", lambda k=kind: [_Dev(k)])
+        s = probe_host()
+        assert s.chip is TPU_V5E and not s.interpret_kernels
+    # a TPU or a backend without a ChipSpec raises instead of borrowing one
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("TPU v4")])
+    with pytest.raises(ValueError, match="TPU v4"):
+        probe_host()
+    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+    with pytest.raises(ValueError, match="metal"):
+        probe_host()

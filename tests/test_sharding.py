@@ -4,14 +4,12 @@ import jax
 import pytest
 from jax.sharding import AbstractMesh, PartitionSpec
 
+from repro.launch.mesh import parse_mesh
 from repro.models.sharding import (RULE_SETS, ShardingPlan, zero1_axes)
 
 
 def _abstract_mesh(shape, axes):
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:   # jax <= 0.4.37: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)
 
 
 def _plan(rules_name, shape=(16, 16), axes=("data", "model")):
@@ -102,3 +100,19 @@ def test_zero1_places_on_largest_free_dim():
     # nothing free & divisible -> unchanged
     axes = zero1_axes(("vocab",), p, (100,))
     assert axes == ("vocab",)
+
+
+@pytest.mark.parametrize("text,want", [
+    ("1", ((1,), ("data",))),
+    ("4", ((4,), ("data",))),
+    ("1x4", ((1, 4), ("data", "model"))),
+    ("2X2", ((2, 2), ("data", "model"))),
+])
+def test_parse_mesh(text, want):
+    assert parse_mesh(text) == want
+
+
+@pytest.mark.parametrize("text", ["0", "1x0", "2x2x2", "x4", "four"])
+def test_parse_mesh_rejects_malformed(text):
+    with pytest.raises(ValueError):
+        parse_mesh(text)
