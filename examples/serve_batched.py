@@ -54,7 +54,7 @@ def main():
     dt = time.perf_counter() - t0
 
     toks = sum(len(r.tokens) for r in responses)
-    lat = sorted(r.queued_s for r in responses)
+    lat = sorted(r.queued_s + r.prefill_s + r.decode_s for r in responses)
     print(f"{len(responses)} responses, {toks} tokens, {dt:.1f}s wall "
           f"({toks/dt:.1f} tok/s, {engine._ticks} fused decode ticks)")
     print(f"latency p50={lat[len(lat)//2]*1e3:.0f}ms "

@@ -28,6 +28,7 @@ from ..core import (CompileCache, InstanceSnapshot, LazyBuilder, PreBuilder,
                     SPEC_LEASE_PREFIX, probe_host, restore_instance,
                     snapshot_instance, write_sbom)
 from ..core import catalog
+from ..spans import CompileCounter
 from .jax_cache import enable_compile_cache
 from .mesh import _make_mesh, parse_mesh
 
@@ -178,23 +179,25 @@ def main(argv=None) -> int:
             print("instance content demoted to the speculative eviction "
                   "tier (evictable first; restore promotes it back)")
 
-    params = init_params(inst, args.seed)
-    engine = inst.entry["make_engine"](
-        params, num_slots=args.slots, max_seq=args.max_seq,
-        prefill_buckets=(32,))
+    with CompileCounter() as compiles:
+        params = init_params(inst, args.seed)
+        engine = inst.entry["make_engine"](
+            params, num_slots=args.slots, max_seq=args.max_seq,
+            prefill_buckets=(32,))
 
-    rng = np.random.default_rng(args.seed)
-    t0 = time.perf_counter()
-    for i in range(args.requests):
-        ln = int(rng.integers(4, 24))
-        engine.submit(rng.integers(1, cfg.vocab, ln).tolist(),
-                      max_new_tokens=args.max_new,
-                      temperature=args.temperature)
-    resp = engine.run_until_drained()
-    dt = time.perf_counter() - t0
+        rng = np.random.default_rng(args.seed)
+        t0 = time.perf_counter()
+        for i in range(args.requests):
+            ln = int(rng.integers(4, 24))
+            engine.submit(rng.integers(1, cfg.vocab, ln).tolist(),
+                          max_new_tokens=args.max_new,
+                          temperature=args.temperature)
+        resp = engine.run_until_drained()
+        dt = time.perf_counter() - t0
     toks = sum(len(r.tokens) for r in resp)
     print(f"{len(resp)} responses, {toks} tokens in {dt:.1f}s "
           f"({toks/dt:.1f} tok/s, {engine._ticks} engine ticks)")
+    print(compiles.summary())
     return 0
 
 

@@ -8,6 +8,11 @@ The engine owns ``num_slots`` cache slots.  Each engine tick:
      each at its own depth (vector ``cache_pos``);
   3. retire — slots that hit EOS/max_tokens emit a finished response.
 
+Admission, the decode dispatch and the token sync each run inside a program
+span (``spans.NAMES``), so a profiler trace shows what the host did while
+the device idled.  Every request is stamped when it is submitted, when its
+admission starts and when its first token is on the host.
+
 Everything jitted is shape-stable: (num_slots, 1) decode, a fixed set of
 prefill buckets — no recompiles in steady state.
 
@@ -18,6 +23,7 @@ so a model spread over several chips stays spread.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -26,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .models.common import P
 from .models.sharding import use_plan
 
@@ -36,17 +43,24 @@ class Request:
     prompt: np.ndarray                   # (prompt_len,) int32
     max_new_tokens: int = 32
     temperature: float = 0.0             # 0 = greedy
-    submitted_at: float = 0.0
+    submitted_at: float = 0.0            # time.perf_counter() stamps
+    admitted_at: float = math.nan        # admission starts
+    first_token_at: float = math.nan     # first token on the host
 
 
 @dataclasses.dataclass
 class Response:
+    """A finished request.  ``queued_s`` (submit to admission), ``prefill_s``
+    (admission to the first token on the host: prefill, slot insert, first
+    token sync) and ``decode_s`` (first token to finish) add up to the
+    request's time in the engine."""
     rid: int
     tokens: List[int]
     prompt_len: int
     queued_s: float
     prefill_s: float
     decode_s: float
+    first_token_at: float
 
 
 class ServingEngine:
@@ -78,8 +92,6 @@ class ServingEngine:
         self.slot_req: List[Optional[Request]] = [None] * num_slots
         self.slot_pos = np.zeros(num_slots, np.int32)       # next write pos
         self.slot_out: List[List[int]] = [[] for _ in range(num_slots)]
-        self.slot_t0 = np.zeros(num_slots, np.float64)
-        self.slot_tprefill = np.zeros(num_slots, np.float64)
         self.finished: List[Response] = []
         self._next_tokens = np.zeros(num_slots, np.int32)
         self._key = jax.random.PRNGKey(rng_seed)
@@ -219,18 +231,18 @@ class ServingEngine:
         for slot in range(self.num_slots):
             if self.slot_req[slot] is not None or not self.queue:
                 continue
-            req = self.queue.popleft()
-            t0 = time.perf_counter()
-            logits, cache1, n_kept = self.prefill(req.prompt)
-            self.cache = self._insert(self.cache, cache1,
-                                      jnp.asarray(slot, jnp.int32))
-            first = int(jax.device_get(jnp.argmax(logits[0])))
-            self.slot_req[slot] = req
-            self.slot_pos[slot] = n_kept
-            self.slot_out[slot] = [first]
-            self._next_tokens[slot] = first
-            self.slot_t0[slot] = req.submitted_at
-            self.slot_tprefill[slot] = time.perf_counter() - t0
+            with spans.span(spans.ADMIT):
+                req = self.queue.popleft()
+                req.admitted_at = time.perf_counter()
+                logits, cache1, n_kept = self.prefill(req.prompt)
+                self.cache = self._insert(self.cache, cache1,
+                                          jnp.asarray(slot, jnp.int32))
+                first = int(jax.device_get(jnp.argmax(logits[0])))
+                req.first_token_at = time.perf_counter()
+                self.slot_req[slot] = req
+                self.slot_pos[slot] = n_kept
+                self.slot_out[slot] = [first]
+                self._next_tokens[slot] = first
 
     def _retire(self) -> None:
         now = time.perf_counter()
@@ -245,9 +257,10 @@ class ServingEngine:
                 self.finished.append(Response(
                     rid=req.rid, tokens=list(out),
                     prompt_len=len(req.prompt),
-                    queued_s=now - req.submitted_at,
-                    prefill_s=float(self.slot_tprefill[slot]),
-                    decode_s=now - self.slot_t0[slot]))
+                    queued_s=req.admitted_at - req.submitted_at,
+                    prefill_s=req.first_token_at - req.admitted_at,
+                    decode_s=now - req.first_token_at,
+                    first_token_at=req.first_token_at))
                 self.slot_req[slot] = None
                 self.slot_out[slot] = []
 
@@ -259,22 +272,24 @@ class ServingEngine:
         live = np.array([r is not None for r in self.slot_req])
         if not live.any():
             return 0
-        positions = np.where(live, self.slot_pos, self.max_seq - 1) \
-            .astype(np.int32)
-        temps = np.array([
-            (r.temperature if r is not None else 0.0)
-            for r in self.slot_req], np.float32)
-        self._key, sub = jax.random.split(self._key)
-        next_tok, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self._next_tokens),
-            jnp.asarray(positions), jnp.asarray(live), sub,
-            jnp.asarray(temps))
-        next_tok = np.asarray(jax.device_get(next_tok))
-        for slot in range(self.num_slots):
-            if live[slot]:
-                self.slot_out[slot].append(int(next_tok[slot]))
-                self.slot_pos[slot] += 1
-                self._next_tokens[slot] = next_tok[slot]
+        with spans.span(spans.DECODE):
+            positions = np.where(live, self.slot_pos, self.max_seq - 1) \
+                .astype(np.int32)
+            temps = np.array([
+                (r.temperature if r is not None else 0.0)
+                for r in self.slot_req], np.float32)
+            self._key, sub = jax.random.split(self._key)
+            next_tok, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self._next_tokens),
+                jnp.asarray(positions), jnp.asarray(live), sub,
+                jnp.asarray(temps))
+        with spans.span(spans.TOKEN_SYNC):
+            next_tok = np.asarray(jax.device_get(next_tok))
+            for slot in range(self.num_slots):
+                if live[slot]:
+                    self.slot_out[slot].append(int(next_tok[slot]))
+                    self.slot_pos[slot] += 1
+                    self._next_tokens[slot] = next_tok[slot]
         self._ticks += 1
         self._retire()
         return int(live.sum())
