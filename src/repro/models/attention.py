@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Mapping, Optional, Tuple
+from typing import Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -138,6 +138,61 @@ def lax_flash_attention(q, k, v, *, scale, causal=True, window=0,
     return o.astype(v.dtype)
 
 
+class KVWrite(NamedTuple):
+    """One decode step's new key and value, (..., b, kv, 1, hd) each, not
+    yet written into their full-length cache."""
+    k: jax.Array
+    v: jax.Array
+
+
+def decode_attention(q, k, v, k_new, v_new, *, scale, pos, window=0,
+                     softcap=0.0):
+    """One query token per sequence (q: (b, hq, 1, d)) over the cache's
+    positions < ``pos`` and its own key ``k_new`` (b, hkv, 1, d), in one
+    softmax: the same as ``naive_attention`` over the cache with the token
+    written at ``pos`` and ``kv_len = pos + 1``, without writing it.
+    ``pos`` is a scalar or a (b,) vector."""
+    b, hq, _, d = q.shape
+    hkv, skv = k.shape[1], k.shape[2]
+    qf = q.reshape(b, hkv, hq // hkv, d).astype(jnp.float32)
+    s_c = jnp.einsum("bhgd,bhkd->bhgk", qf, k.astype(jnp.float32)) * scale
+    s_n = jnp.einsum("bhgd,bhd->bhg", qf,
+                     k_new[:, :, 0].astype(jnp.float32))[..., None] * scale
+    if softcap:
+        s_c = softcap * jnp.tanh(s_c / softcap)
+        s_n = softcap * jnp.tanh(s_n / softcap)
+    qpos = jnp.asarray(pos).reshape(-1, 1, 1, 1)
+    kpos = jnp.arange(skv)
+    mask = kpos < qpos
+    if window:
+        mask &= qpos - kpos < window
+    s_c = jnp.where(mask, s_c, NEG_INF)
+    p = jax.nn.softmax(jnp.concatenate([s_c, s_n], axis=-1), axis=-1)
+    o = jnp.einsum("bhgk,bhkd->bhgd", p[..., :skv], v.astype(jnp.float32)) \
+        + p[..., skv:] * v_new[:, :, 0][:, :, None].astype(jnp.float32)
+    return o.reshape(b, hq, 1, d).astype(v.dtype)
+
+
+def write_token(cache, new, pos):
+    """``cache`` (..., b, kv, S, hd) with ``new`` (..., b, kv, 1, hd) written
+    at sequence position ``pos`` (a scalar, or a (b,) vector: one position
+    per sequence)."""
+    pos = jnp.asarray(pos)
+    new = new.astype(cache.dtype)
+    if pos.ndim == 0:
+        start = (0,) * (cache.ndim - 2) + (pos, 0)
+        return jax.lax.dynamic_update_slice(cache, new, start)
+    # one slice update per sequence: a scatter would have XLA lay the
+    # cache out anew (a whole copy in and out), a slice update keeps its
+    # layout, so a donated cache is written in place
+    lead = cache.ndim - 4
+    for i in range(cache.shape[lead]):
+        start = (0,) * lead + (i, 0, pos[i], 0)
+        cache = jax.lax.dynamic_update_slice(
+            cache, jax.lax.slice_in_dim(new, i, i + 1, axis=lead), start)
+    return cache
+
+
 # ---------------------------------------------------------------------------
 # GQA module
 # ---------------------------------------------------------------------------
@@ -168,9 +223,15 @@ def gqa_attention(params, x, cfg, *, positions, kernel=lax_flash_attention,
                   window=0, cache=None, cache_pos=None,
                   query_scale: Optional[float] = None):
     """Returns (out, new_cache).  ``kernel`` is the selected attention
-    callable.  Train: cache=None.  Decode: cache is {'k': (b, kv, S, hd),
-    'v': ...} updated at cache_pos (int32 scalar; the Python int 0 marks a
-    fresh prefill)."""
+    callable.  Train: cache=None, new_cache None.  Otherwise cache is
+    {'k': (b, kv, S, hd), 'v': ...} and cache_pos an int32 scalar or a
+    (b,) vector (the Python int 0 marks a fresh prefill):
+
+    * one query token over a full-length cache (not a ring): the cache is
+      only read, and new_cache is the token's ``KVWrite``, which the caller
+      writes at cache_pos (``write_token``);
+    * a prefill chunk, or any step over a sliding-window ring cache
+      (S <= window): new_cache is the whole updated cache."""
     b, s, dm = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv, cfg.head_dim
     q = _proj(x, params["wq"], params.get("bq")).reshape(b, s, h, hd)
@@ -235,6 +296,15 @@ def gqa_attention(params, x, cfg, *, positions, kernel=lax_flash_attention,
                     cv = jax.lax.dynamic_update_slice(
                         cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0))
                 new_cache = {"k": ck, "v": cv}
+        elif s == 1:
+            # decode: the cache is only read; the new token is attended
+            # beside it and handed back for the caller to write
+            kn = k.astype(cache["k"].dtype)
+            vn = v.astype(cache["v"].dtype)
+            o = decode_attention(q, cache["k"], cache["v"], kn, vn,
+                                 scale=scale, pos=cache_pos, window=window,
+                                 softcap=cfg.attn_softcap)
+            new_cache = KVWrite(kn, vn)
         else:
             if per_slot:
                 # continuous batching: each slot writes at its own position
@@ -250,11 +320,7 @@ def gqa_attention(params, x, cfg, *, positions, kernel=lax_flash_attention,
                     cache["v"], v.astype(cache["v"].dtype),
                     (0, 0, cache_pos, 0))
             new_cache = {"k": ck, "v": cv}
-            if s == 1:   # decode: one query over the cache, O(S) per step
-                o = naive_attention(q, ck, cv, scale=scale, causal=False,
-                                    window=window, softcap=cfg.attn_softcap,
-                                    q_offset=cache_pos, kv_len=cache_pos + 1)
-            elif isinstance(cache_pos, int) and cache_pos == 0:
+            if isinstance(cache_pos, int) and cache_pos == 0:
                 # fresh prefill: the cache holds nothing before this chunk,
                 # so the chunk attends only to itself
                 o = kernel(q, k, v, scale=scale, causal=True,

@@ -10,7 +10,12 @@ Families
   vlm-lm     : dense decoder with M-RoPE + injected patch embeddings (stub)
 
 Layers are stacked and driven by ``lax.scan`` (small HLO, fast compile, the
-MaxText idiom); KV caches / recurrent states ride along as scan xs/ys.
+MaxText idiom).  Caches go into the scan as xs.  In a decode step a
+full-length GQA K/V cache is only read there: each layer hands back its new
+token (``KVWrite``) as ys, and after the scan each token is written into the
+stacked cache at its slot's position, so a donated cache is updated in
+place.  Everything else (prefill, sliding-window ring caches, MLA caches,
+recurrent states) comes back whole as ys.
 """
 from __future__ import annotations
 
@@ -21,9 +26,9 @@ from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import (gqa_attention, gqa_cache_spec, gqa_spec,
+from .attention import (KVWrite, gqa_attention, gqa_cache_spec, gqa_spec,
                         lax_flash_attention, mla_attention, mla_cache_spec,
-                        mla_spec)
+                        mla_spec, write_token)
 from .common import (P, SpecTree, apply_norm, axes_tree, cross_entropy,
                      embed_spec, embed_tokens, eval_shape_tree, head_spec,
                      init_tree, lm_logits, norm_spec, rms_norm,
@@ -97,8 +102,11 @@ class Model:
         return axes_tree(self.cache_specs(batch, max_seq))
 
     # -- forward ----------------------------------------------------------
-    def backbone(self, params, x, positions, cache=None, cache_pos=0):
-        """x: (b, s, d) embeddings → (h, new_cache, aux)."""
+    def backbone(self, params, x, positions, cache=None, cache_pos=0,
+                 cache_paths=None):
+        """x: (b, s, d) embeddings → (h, new_cache, aux).  ``cache_paths``,
+        a dict, counts the cache leaves written in place ("in_place") and
+        those the scan hands back whole ("scanned")."""
         aux = jnp.zeros((), jnp.float32)
         new_cache: Dict[str, Any] = {}
         for st in self.stacks:
@@ -121,12 +129,14 @@ class Model:
             (x, aux), c_out = jax.lax.scan(
                 scan_fn, (x, aux), (stacked_params, st_cache))
             if st_cache is not None:
-                new_cache[st.name] = c_out
+                new_cache[st.name] = _write_back(st_cache, c_out, cache_pos,
+                                                 cache_paths)
         return x, (new_cache if cache is not None else None), aux
 
-    def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0):
+    def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0,
+                  cache_paths=None):
         h, new_cache, aux = self.backbone(params, embeds, positions,
-                                          cache, cache_pos)
+                                          cache, cache_pos, cache_paths)
         h = apply_norm(params["final_norm"], h, self.cfg,
                        self.variants.rms_norm)
         logits = lm_logits(params.get("head", {}), params["embed"], h,
@@ -203,7 +213,8 @@ class Model:
                            self.cfg)
         return logits[:, 0, :], cache
 
-    def decode_step(self, params, tokens, positions, cache, cache_pos):
+    def decode_step(self, params, tokens, positions, cache, cache_pos,
+                    cache_paths=None):
         """tokens: (b, 1); positions: (b, 1) or (3, b, 1)."""
         batch = {"tokens": tokens}
         if self.cfg.family == "audio-lm":
@@ -214,8 +225,26 @@ class Model:
         else:
             e = embed_tokens(params["embed"], tokens, self.cfg)
         logits, _, cache, _ = self.logits_fn(params, e, positions, cache,
-                                             cache_pos)
+                                             cache_pos, cache_paths)
         return logits[:, -1, :], cache
+
+
+def _write_back(cache, out, cache_pos, paths):
+    """A stack's cache after its scan: each ``KVWrite`` in the scan's ys
+    ((layers, b, kv, 1, hd) leaves) is written into its cache at
+    ``cache_pos``; every other subtree of ys is the new cache as it is."""
+    if isinstance(out, KVWrite):
+        new = {"k": write_token(cache["k"], out.k, cache_pos),
+               "v": write_token(cache["v"], out.v, cache_pos)}
+        path = "in_place"
+    elif isinstance(out, dict):
+        return {name: _write_back(cache[name], o, cache_pos, paths)
+                for name, o in out.items()}
+    else:
+        new, path = out, "scanned"
+    if paths is not None:
+        paths[path] = paths.get(path, 0) + len(jax.tree.leaves(new))
+    return new
 
 
 # ---------------------------------------------------------------------------

@@ -96,6 +96,9 @@ class ServingEngine:
         self._next_tokens = np.zeros(num_slots, np.int32)
         self._key = jax.random.PRNGKey(rng_seed)
         self._ticks = 0
+        # cache leaves the decode step writes in place or gets back whole
+        # from the layer scan; counted each time the step is traced
+        self.decode_cache_paths: Optional[Dict[str, int]] = None
 
         # jitted single-slot prefill (per bucket), slot insert and fused
         # decode; the cache keeps the plan's shardings across all three
@@ -162,8 +165,10 @@ class ServingEngine:
             pos_in = jnp.broadcast_to(pos, (3,) + pos.shape)
         else:
             pos_in = pos
+        paths = {"in_place": 0, "scanned": 0}
         logits, new_cache = m.decode_step(params, toks, pos_in, cache,
-                                          positions)
+                                          positions, cache_paths=paths)
+        self.decode_cache_paths = paths
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         gumbel = -jnp.log(-jnp.log(
             jax.random.uniform(key, logits.shape, minval=1e-9, maxval=1.0)))

@@ -222,7 +222,8 @@ def test_ring_buffer_window_decode_matches_full_cache():
     """Sliding-window ring cache (len=window) decode == full cache decode
     with window masking."""
     from repro.configs import ARCHS
-    from repro.models.attention import gqa_attention, gqa_cache_spec
+    from repro.models.attention import (gqa_attention, gqa_cache_spec,
+                                        write_token)
     from repro.models.common import init_tree
     from repro.models.attention import gqa_spec
     import dataclasses
@@ -243,9 +244,13 @@ def test_ring_buffer_window_decode_matches_full_cache():
     for t in range(total):
         xt = x[:, t:t + 1]
         pt = pos[:, t:t + 1]
-        o_full, full_cache = gqa_attention(
+        o_full, kv = gqa_attention(
             p, xt, cfg, positions=pt, kernel=naive_attention, window=W,
             cache=full_cache, cache_pos=t)
+        # the full-length cache is only read; the step's token is written
+        # by the caller
+        full_cache = {"k": write_token(full_cache["k"], kv.k, t),
+                      "v": write_token(full_cache["v"], kv.v, t)}
         o_ring, ring_cache = gqa_attention(
             p, xt, cfg, positions=pt, kernel=naive_attention, window=W,
             cache=ring_cache, cache_pos=t)

@@ -41,7 +41,7 @@ def _isolated_greedy(model, params, prompt, n_new, max_seq=64):
 
 
 @pytest.mark.parametrize("arch_id", ["codeqwen1.5-7b", "gemma2-9b",
-                                     "jamba-v0.1-52b"])
+                                     "jamba-v0.1-52b", "phi4-mini-3.8b"])
 def test_continuous_batching_matches_isolated_decode(arch_id, service,
                                                      smoke_mesh):
     cfg = ARCHS[arch_id].reduced()
