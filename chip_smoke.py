@@ -134,8 +134,8 @@ def kernel_phase(seed: int) -> None:
 
     # rwkv6-1.6b: 32 heads of 64; 50 tokens, padded to the 64-token chunk
     rkv = [normal(1, 32, 50, 64) for _ in range(3)]
-    decay = jax.nn.sigmoid(normal(1, 32, 50, 64)).astype(jnp.bfloat16)
-    wkv = rkv + [decay, normal(32, 64, scale=0.1)]
+    log_decay = -jnp.exp(normal(1, 32, 50, 64).astype(jnp.float32) - 1.0)
+    wkv = rkv + [log_decay, normal(32, 64, scale=0.1)]
     # phi4-mini-3.8b: 24 query heads on 8 kv heads of 128; one 64 bucket
     qkv = [normal(1, 24, 64, 128), normal(1, 8, 64, 128),
            normal(1, 8, 64, 128)]

@@ -70,6 +70,9 @@ class ArchConfig:
     frontend: str = ""              # "audio-frames" | "vision-patches" | ""
     codebooks: int = 0              # musicgen
     dtype: str = "bfloat16"
+    # activations and the residual stream; "" keeps ``dtype``.  float32
+    # activations multiply at float32 precision; the weights stay ``dtype``.
+    act_dtype: str = ""
     max_seq: int = 8192
 
     # --- declared direct dependencies (pre-builder may extend/filter)

@@ -20,5 +20,10 @@ CONFIG = ArchConfig(
     norm="ln",
     rwkv_head_size=64,
     dtype="bfloat16",
+    # bfloat16 activations put the served logits about one standard
+    # deviation from a float32 forward pass of the same bfloat16 weights:
+    # every rounding in the 24 layers grows through the time mix's
+    # products (docs/architecture.md, "RWKV-6 precision")
+    act_dtype="float32",
     notes="WKV6 chunked scan; O(1) decode state (no KV cache).",
 )

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+from .. import spans
 from ..models.sharding import current_plan
 from .flash_attention import flash_attention
 from .rmsnorm import rmsnorm_pallas
@@ -86,29 +87,30 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def pallas_wkv6(r, k, v, w, u, state=None, *, interpret: bool,
+def pallas_wkv6(r, k, v, lw, u, state=None, *, interpret: bool,
                 chunk: int = 64):
-    """WKV-impl interface over the Pallas WKV6 kernel.
+    """WKV-impl interface over the Pallas WKV6 kernel; ``lw`` is the
+    log-decay in float32.
 
     Any length runs the kernel: the tail is padded to the chunk with
-    ``r = k = v = 0`` and ``w = 1``, which leaves the output of the real
-    tokens and the carried state exact.  Short inputs (decode) use one
-    chunk of 16, the bf16 sublane tile.
+    ``r = k = v = 0`` and ``lw = 0`` (w = 1), which leaves the output of
+    the real tokens and the carried state exact.  Short inputs (decode) use
+    one chunk of 16, the bf16 sublane tile.
     """
     b, h, s, K = r.shape
     V = v.shape[-1]
     L = chunk if s >= chunk else _round_up(s, 16)
     pad = _round_up(s, L) - s
+    spans.report("wkv", real=b * s, computed=b * (s + pad))
     if pad:
         widths = ((0, 0), (0, 0), (0, pad), (0, 0))
-        r, k, v = (jnp.pad(t, widths) for t in (r, k, v))
-        w = jnp.pad(w, widths, constant_values=1)
+        r, k, v, lw = (jnp.pad(t, widths) for t in (r, k, v, lw))
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
     fn = functools.partial(wkv6_pallas, chunk=L, interpret=interpret)
     spec = _heads_spec(r)
     u_spec = PartitionSpec(spec[1], None)
-    y, s_out = _per_shard(fn, (r, k, v, w, u, state),
+    y, s_out = _per_shard(fn, (r, k, v, lw, u, state),
                           (spec, spec, spec, spec, u_spec, spec),
                           (spec, spec))
     return y[:, :, :s], s_out

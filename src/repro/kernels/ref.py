@@ -43,10 +43,11 @@ def attention_ref(q, k, v, *, scale: float, causal: bool = True,
 # WKV6 (RWKV 'Finch' recurrence with data-dependent decay)
 # ---------------------------------------------------------------------------
 
-def wkv6_ref(r, k, v, w, u, state=None):
+def wkv6_ref(r, k, v, lw, u, state=None):
     """Exact sequential recurrence.
 
-    r,k,w: (b, h, s, K); v: (b, h, s, V); u: (h, K); state: (b, h, K, V).
+    r,k: (b, h, s, K); lw: (b, h, s, K) log-decay, w = exp(lw); v: (b, h,
+    s, V); u: (h, K); state: (b, h, K, V).
     y_t = r_t · (S_{t-1} + diag(u) k_t v_t^T);  S_t = diag(w_t) S_{t-1} + k_t v_t^T
     """
     b, h, s, K = r.shape
@@ -56,7 +57,7 @@ def wkv6_ref(r, k, v, w, u, state=None):
     rf = r.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
-    wf = w.astype(jnp.float32)
+    wf = jnp.exp(lw.astype(jnp.float32))
     uf = u.astype(jnp.float32)[None]
     ys = []
     for t in range(s):

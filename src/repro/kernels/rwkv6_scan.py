@@ -9,6 +9,9 @@ VMEM scratch and persists over the sequential chunk grid dimension.
     y_t = r_t · (S_{t-1} + diag(u) k_t v_t^T)
     S_t = diag(w_t) S_{t-1} + k_t v_t^T         with 0 < w < 1
 
+The decay comes in as lw = log w (≤ 0) in float32, as the model computes it
+(lw = -exp(decay + dd)): the kernel works in log w throughout.
+
 Every exponential computed here has exponent ≤ 0 (decays multiply), so the
 chunked form is overflow-safe in f32 regardless of sequence length or decay
 strength.
@@ -121,10 +124,11 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_pallas(r, k, v, w, u, state=None, *, chunk: int = 64,
+def wkv6_pallas(r, k, v, lw, u, state=None, *, chunk: int = 64,
                 interpret: bool):
-    """r,k,w: (b, h, s, K); v: (b, h, s, V); u: (h, K); ``s`` a multiple
-    of ``chunk`` (``kernels.ops.pallas_wkv6`` pads).
+    """r,k: (b, h, s, K); lw: (b, h, s, K) log-decay in float32; v: (b, h,
+    s, V); u: (h, K); ``s`` a multiple of ``chunk``
+    (``kernels.ops.pallas_wkv6`` pads).
     Returns (y (b, h, s, V), final_state (b, h, K, V) f32)."""
     b, h, s, K = r.shape
     V = v.shape[-1]
@@ -135,7 +139,7 @@ def wkv6_pallas(r, k, v, w, u, state=None, *, chunk: int = 64,
     n = s // L
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
-    lw = jnp.log(jnp.maximum(w.astype(jnp.float32), 1e-38))
+    lw = lw.astype(jnp.float32)
     u3 = u.reshape(h, 1, K)
 
     kernel = functools.partial(_wkv6_kernel, L=L, K=K, nchunks=n)

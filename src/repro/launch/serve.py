@@ -199,6 +199,8 @@ def main(argv=None) -> int:
           f"({toks/dt:.1f} tok/s, {engine._ticks} engine ticks)")
     print(compiles.summary())
     print("decode cache leaves:", engine.decode_cache_paths)
+    if engine.wkv_tokens:
+        print("wkv tokens per layer, real / computed:", engine.wkv_tokens)
     return 0
 
 

@@ -7,6 +7,7 @@ sharding plan can map every leaf without drift.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
@@ -198,9 +199,19 @@ def head_spec(cfg) -> SpecTree:
 
 def embed_tokens(params, tokens, cfg):
     e = params["tok"][tokens]          # (b, s, d)
+    if cfg.act_dtype:
+        e = e.astype(cfg.act_dtype)
     if cfg.arch_id.startswith("gemma"):
         e = e * jnp.asarray(math.sqrt(cfg.d_model), e.dtype)
     return e
+
+
+def matmul_precision(cfg):
+    """The matmul precision of ``cfg``'s activations: float32 ones at
+    float32 (the TPU's default rounds each operand to bfloat16)."""
+    if cfg.act_dtype == "float32":
+        return jax.default_matmul_precision("float32")
+    return contextlib.nullcontext()
 
 
 def lm_logits(head_params, embed_params, x, cfg):

@@ -14,21 +14,27 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from .common import P, SpecTree, rms_norm
 from .sharding import shard
 
 # ---------------------------------------------------------------------------
-# WKV6 core: r,k,w: (b, h, s, K); v: (b, h, s, V); u: (h, K)
+# WKV6 core: r,k,lw: (b, h, s, K); v: (b, h, s, V); u: (h, K)
 # recurrence: y_t = r_t·(S_{t-1} + diag(u) k_t v_t^T);  S_t = diag(w_t) S_{t-1} + k_t v_t^T
+# Every implementation takes the decay as lw = log w = -exp(decay + dd) in
+# float32: near w = 1 (the channels with the longest memory) bfloat16 steps
+# by 2^-8 and would round w above 0.998 to 1, no decay at all.  r, k, v come
+# in the activation dtype; the state is float32.
 # ---------------------------------------------------------------------------
 
-def wkv6_sequential(r, k, v, w, u, state=None):
+def wkv6_sequential(r, k, v, lw, u, state=None):
     b, h, s, K = r.shape
     V = v.shape[-1]
+    spans.report("wkv", real=b * s, computed=b * s)
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
     rf, kf, vf = (t.astype(jnp.float32) for t in (r, k, v))
-    wf = w.astype(jnp.float32)
+    wf = jnp.exp(lw.astype(jnp.float32))
     uf = u.astype(jnp.float32)[None, :, :]          # (1, h, K)
 
     def step(S, t):
@@ -44,12 +50,13 @@ def wkv6_sequential(r, k, v, w, u, state=None):
     return jnp.moveaxis(ys, 0, 2).astype(v.dtype), S
 
 
-def wkv6_chunked(r, k, v, w, u, state=None, chunk: int = 32):
+def wkv6_chunked(r, k, v, lw, u, state=None, chunk: int = 32):
     """Chunked parallel WKV6.  All exponentials have exponent ≤ 0."""
     b, h, s, K = r.shape
     V = v.shape[-1]
     if s % chunk or s <= chunk:
-        return wkv6_sequential(r, k, v, w, u, state)
+        return wkv6_sequential(r, k, v, lw, u, state)
+    spans.report("wkv", real=b * s, computed=b * s)
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
     n = s // chunk
@@ -57,8 +64,7 @@ def wkv6_chunked(r, k, v, w, u, state=None, chunk: int = 32):
     rf = r.astype(jnp.float32).reshape(b, h, n, L, K)
     kf = k.astype(jnp.float32).reshape(b, h, n, L, K)
     vf = v.astype(jnp.float32).reshape(b, h, n, L, V)
-    lw = jnp.log(jnp.maximum(w.astype(jnp.float32), 1e-38)
-                 ).reshape(b, h, n, L, K)
+    lw = lw.astype(jnp.float32).reshape(b, h, n, L, K)
     uf = u.astype(jnp.float32)[None, :, None, :]     # (1, h, 1, K)
 
     sw = jnp.cumsum(lw, axis=3) - lw                 # exclusive cumsum
@@ -141,7 +147,7 @@ def _token_shift(x, prev):
 def rwkv6_time_mix(p, x, cfg, state=None, wkv_impl=wkv6_chunked):
     """x: (b, s, d).  state: None (train, zero init) or dict with
     'shift' (b, d) and 'wkv' (b, h, K, V).  ``wkv_impl`` is the selected
-    WKV6 callable."""
+    WKV6 callable; it is handed the log-decay in float32."""
     b, s, d = x.shape
     hs = cfg.rwkv_head_size
     h = d // hs
@@ -167,16 +173,16 @@ def rwkv6_time_mix(p, x, cfg, state=None, wkv_impl=wkv6_chunked):
 
     dd = jnp.tanh(jnp.einsum("bsd,dr->bsr", xw, p["decay_w1"].astype(x.dtype)))
     dd = jnp.einsum("bsr,rd->bsd", dd, p["decay_w2"].astype(x.dtype))
-    w = jnp.exp(-jnp.exp((p["decay"].astype(jnp.float32)
-                          + dd.astype(jnp.float32))))        # (b,s,d) in (0,1)
+    # log w = -exp(decay + dd) <= 0, kept in float32 up to the recurrence
+    lw = -jnp.exp(p["decay"].astype(jnp.float32) + dd.astype(jnp.float32))
 
     def heads(t):
         return jnp.swapaxes(t.reshape(b, s, h, hs), 1, 2)
-    rh, kh, vh, wh = heads(r), heads(k), heads(v), heads(w.astype(x.dtype))
+    rh, kh, vh, lwh = heads(r), heads(k), heads(v), heads(lw)
     rh = shard(rh, "act_batch", "act_heads", "act_seq", None)
 
     wkv_state = state["wkv"] if state is not None else None
-    y, S = wkv_impl(rh, kh, vh, wh, p["faaaa"], wkv_state)
+    y, S = wkv_impl(rh, kh, vh, lwh, p["faaaa"], wkv_state)
     y = jnp.swapaxes(y, 1, 2).reshape(b, s, d)
 
     # per-head group norm
@@ -211,12 +217,13 @@ def rwkv6_state_spec(cfg, batch: int) -> SpecTree:
     d = cfg.d_model
     hs = cfg.rwkv_head_size
     h = d // hs
+    act = cfg.act_dtype or None
     return {
-        "tm_shift": P((batch, d), ("cache_batch", None), "zeros"),
+        "tm_shift": P((batch, d), ("cache_batch", None), "zeros", dtype=act),
         "wkv": P((batch, h, hs, hs),
                  ("cache_batch", "cache_heads", None, None), "zeros",
                  dtype="float32"),
-        "cm_shift": P((batch, d), ("cache_batch", None), "zeros"),
+        "cm_shift": P((batch, d), ("cache_batch", None), "zeros", dtype=act),
     }
 
 

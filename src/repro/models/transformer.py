@@ -31,8 +31,8 @@ from .attention import (KVWrite, gqa_attention, gqa_cache_spec, gqa_spec,
                         mla_spec, write_token)
 from .common import (P, SpecTree, apply_norm, axes_tree, cross_entropy,
                      embed_spec, embed_tokens, eval_shape_tree, head_spec,
-                     init_tree, lm_logits, norm_spec, rms_norm,
-                     sinusoidal_pos, softcap, stacked)
+                     init_tree, lm_logits, matmul_precision, norm_spec,
+                     rms_norm, sinusoidal_pos, softcap, stacked)
 from .ffn import MOE_IMPLS, ffn_apply, ffn_spec, moe_spec
 from .sharding import shard
 from .ssm import (mamba_block, mamba_spec, mamba_state_spec,
@@ -109,28 +109,29 @@ class Model:
         those the scan hands back whole ("scanned")."""
         aux = jnp.zeros((), jnp.float32)
         new_cache: Dict[str, Any] = {}
-        for st in self.stacks:
-            body = st.apply
-            if self.variants.remat != "none" and cache is None:
-                policy = None
-                if self.variants.remat == "dots":
-                    policy = jax.checkpoint_policies.checkpoint_dots
-                body = jax.checkpoint(body, policy=policy,
-                                      static_argnums=())
-            st_cache = cache.get(st.name) if cache is not None else None
+        with matmul_precision(self.cfg):
+            for st in self.stacks:
+                body = st.apply
+                if self.variants.remat != "none" and cache is None:
+                    policy = None
+                    if self.variants.remat == "dots":
+                        policy = jax.checkpoint_policies.checkpoint_dots
+                    body = jax.checkpoint(body, policy=policy,
+                                          static_argnums=())
+                st_cache = cache.get(st.name) if cache is not None else None
 
-            def scan_fn(carry, xs, _body=body):
-                h, a = carry
-                p, c = xs
-                h, c_new, a_l = _body(p, h, positions, c, cache_pos)
-                return (h, a + a_l), c_new
+                def scan_fn(carry, xs, _body=body):
+                    h, a = carry
+                    p, c = xs
+                    h, c_new, a_l = _body(p, h, positions, c, cache_pos)
+                    return (h, a + a_l), c_new
 
-            stacked_params = params[st.name]
-            (x, aux), c_out = jax.lax.scan(
-                scan_fn, (x, aux), (stacked_params, st_cache))
-            if st_cache is not None:
-                new_cache[st.name] = _write_back(st_cache, c_out, cache_pos,
-                                                 cache_paths)
+                stacked_params = params[st.name]
+                (x, aux), c_out = jax.lax.scan(
+                    scan_fn, (x, aux), (stacked_params, st_cache))
+                if st_cache is not None:
+                    new_cache[st.name] = _write_back(st_cache, c_out, cache_pos,
+                                                     cache_paths)
         return x, (new_cache if cache is not None else None), aux
 
     def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0,

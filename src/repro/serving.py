@@ -99,6 +99,11 @@ class ServingEngine:
         # cache leaves the decode step writes in place or gets back whole
         # from the layer scan; counted each time the step is traced
         self.decode_cache_paths: Optional[Dict[str, int]] = None
+        # per program ("prefill": the last prefill traced, "decode"): the
+        # tokens one layer's WKV call is handed in one run ("real") and
+        # those it computes after padding ("computed"); counted each time
+        # the program is traced, no entry for a model without WKV
+        self.wkv_tokens: Dict[str, Dict[str, int]] = {}
 
         # jitted single-slot prefill (per bucket), slot insert and fused
         # decode; the cache keeps the plan's shardings across all three
@@ -134,8 +139,10 @@ class ServingEngine:
             e = e + sinusoidal_pos(pos, self.cfg.d_model).astype(e.dtype)
         else:
             e = m.embed(params, batch)
-        logits, _, cache, _ = m.logits_fn(params, e, batch["positions"],
-                                          cache, 0)
+        with spans.TraceCount() as count:
+            logits, _, cache, _ = m.logits_fn(params, e, batch["positions"],
+                                              cache, 0)
+        self._note_wkv("prefill", count)
         last = jnp.take_along_axis(
             logits, (length - 1)[None, None, None].astype(jnp.int32)
             if jnp.ndim(length) == 0 else length[:, None, None], axis=1)
@@ -166,9 +173,11 @@ class ServingEngine:
         else:
             pos_in = pos
         paths = {"in_place": 0, "scanned": 0}
-        logits, new_cache = m.decode_step(params, toks, pos_in, cache,
-                                          positions, cache_paths=paths)
+        with spans.TraceCount() as count:
+            logits, new_cache = m.decode_step(params, toks, pos_in, cache,
+                                              positions, cache_paths=paths)
         self.decode_cache_paths = paths
+        self._note_wkv("decode", count)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         gumbel = -jnp.log(-jnp.log(
             jax.random.uniform(key, logits.shape, minval=1e-9, maxval=1.0)))
@@ -179,6 +188,10 @@ class ServingEngine:
         # dead slots must not corrupt their cache position: they decode into
         # position max_seq-1 and their token is ignored on the host.
         return next_tok, new_cache
+
+    def _note_wkv(self, program: str, count: spans.TraceCount) -> None:
+        if "wkv" in count.totals:
+            self.wkv_tokens[program] = count.totals["wkv"]
 
     # -- public API -----------------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 32,

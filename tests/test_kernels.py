@@ -105,6 +105,12 @@ def test_lax_flash_matches_ref_and_naive():
 # wkv6
 # ---------------------------------------------------------------------------
 
+def _log_decay(key, shape):
+    """log w in float32 for w in (0.05, 0.95)."""
+    w = jax.nn.sigmoid(jax.random.normal(key, shape)) * 0.9 + 0.05
+    return jnp.log(w)
+
+
 @pytest.mark.parametrize("b,h,s,K,chunk", [
     (1, 2, 128, 32, 32),
     (2, 3, 64, 16, 16),
@@ -115,10 +121,10 @@ def test_wkv6_pallas_vs_ref(b, h, s, K, chunk):
     r = jax.random.normal(ks[0], (b, h, s, K))
     k = jax.random.normal(ks[1], (b, h, s, K))
     v = jax.random.normal(ks[2], (b, h, s, K))
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
+    lw = _log_decay(ks[3], (b, h, s, K))
     u = jax.random.normal(ks[4], (h, K)) * 0.1
-    y, S = wkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=True)
-    ye, Se = ref.wkv6_ref(r, k, v, w, u)
+    y, S = wkv6_pallas(r, k, v, lw, u, chunk=chunk, interpret=True)
+    ye, Se = ref.wkv6_ref(r, k, v, lw, u)
     np.testing.assert_allclose(y, ye, atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
 
@@ -129,29 +135,29 @@ def test_wkv6_pallas_with_initial_state():
     r = jax.random.normal(ks[0], (b, h, s, K))
     k = jax.random.normal(ks[1], (b, h, s, K))
     v = jax.random.normal(ks[2], (b, h, s, K))
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
+    lw = _log_decay(ks[3], (b, h, s, K))
     u = jax.random.normal(ks[4], (h, K)) * 0.1
     s0 = jax.random.normal(ks[5], (b, h, K, K), jnp.float32)
-    y, S = wkv6_pallas(r, k, v, w, u, s0, chunk=32, interpret=True)
-    ye, Se = ref.wkv6_ref(r, k, v, w, u, s0)
+    y, S = wkv6_pallas(r, k, v, lw, u, s0, chunk=32, interpret=True)
+    ye, Se = ref.wkv6_ref(r, k, v, lw, u, s0)
     np.testing.assert_allclose(y, ye, atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
 
 
 @pytest.mark.parametrize("s", [1, 50, 100])
 def test_pallas_wkv6_pads_any_length_exactly(s):
-    """The wrapper pads the tail (r = k = v = 0, w = 1) instead of falling
+    """The wrapper pads the tail (r = k = v = 0, lw = 0) instead of falling
     back: output and carried state match the recurrence at any length."""
     b, h, K = 1, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(9), 6)
     r = jax.random.normal(ks[0], (b, h, s, K))
     k = jax.random.normal(ks[1], (b, h, s, K))
     v = jax.random.normal(ks[2], (b, h, s, K))
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
+    lw = _log_decay(ks[3], (b, h, s, K))
     u = jax.random.normal(ks[4], (h, K)) * 0.1
     s0 = jax.random.normal(ks[5], (b, h, K, K), jnp.float32)
-    y, S = pallas_wkv6(r, k, v, w, u, s0, interpret=True)
-    ye, Se = wkv6_sequential(r, k, v, w, u, s0)
+    y, S = pallas_wkv6(r, k, v, lw, u, s0, interpret=True)
+    ye, Se = wkv6_sequential(r, k, v, lw, u, s0)
     assert y.shape == (b, h, s, K)
     np.testing.assert_allclose(y, ye, atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
@@ -163,11 +169,11 @@ def test_wkv6_chunked_and_sequential_match_ref():
     r = jax.random.normal(ks[0], (b, h, s, K))
     k = jax.random.normal(ks[1], (b, h, s, K))
     v = jax.random.normal(ks[2], (b, h, s, K))
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, K))) * 0.9 + 0.05
+    lw = _log_decay(ks[3], (b, h, s, K))
     u = jax.random.normal(ks[4], (h, K)) * 0.1
-    ye, _ = ref.wkv6_ref(r, k, v, w, u)
-    y1, _ = wkv6_sequential(r, k, v, w, u)
-    y2, _ = wkv6_chunked(r, k, v, w, u, chunk=32)
+    ye, _ = ref.wkv6_ref(r, k, v, lw, u)
+    y1, _ = wkv6_sequential(r, k, v, lw, u)
+    y2, _ = wkv6_chunked(r, k, v, lw, u, chunk=32)
     np.testing.assert_allclose(y1, ye, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(y2, ye, atol=2e-4, rtol=2e-4)
 

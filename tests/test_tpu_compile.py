@@ -53,11 +53,12 @@ def _compiled_text(fn, *args) -> str:
 def test_wkv6_compiles_at_rwkv6_widths(one_chip, b, s):
     h, K = 32, 64                   # d_model 2048, head size 64
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    rkvw = [sd((b, h, s, K), jnp.bfloat16)] * 4
-    args = rkvw + [sd((h, K), jnp.bfloat16), sd((b, h, K, K), jnp.float32)]
+    rkv = [sd((b, h, s, K), jnp.bfloat16)] * 3
+    lw = sd((b, h, s, K), jnp.float32)      # log-decay, float32
+    args = rkv + [lw, sd((h, K), jnp.bfloat16), sd((b, h, K, K), jnp.float32)]
     text = _compiled_text(
-        lambda r, k, v, w, u, st: pallas_wkv6(r, k, v, w, u, st,
-                                              interpret=False), *args)
+        lambda r, k, v, lw, u, st: pallas_wkv6(r, k, v, lw, u, st,
+                                               interpret=False), *args)
     assert "tpu_custom_call" in text
 
 
