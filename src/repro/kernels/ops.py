@@ -27,7 +27,7 @@ from .. import spans
 from ..models.sharding import current_plan
 from .flash_attention import flash_attention
 from .rmsnorm import rmsnorm_pallas
-from .rwkv6_scan import wkv6_pallas
+from .rwkv6_scan import wkv6_pallas, wkv6_step_pallas
 
 _HEADS = ("act_batch", "act_heads", None, None)     # (b, h, s, d) layout
 
@@ -89,25 +89,31 @@ def _round_up(n: int, m: int) -> int:
 
 def pallas_wkv6(r, k, v, lw, u, state=None, *, interpret: bool,
                 chunk: int = 64):
-    """WKV-impl interface over the Pallas WKV6 kernel; ``lw`` is the
+    """WKV-impl interface over the Pallas WKV6 kernels; ``lw`` is the
     log-decay in float32.
 
-    Any length runs the kernel: the tail is padded to the chunk with
+    The input's length picks the kernel.  One token (decode) runs the step
+    kernel, which reads and writes the state once and pads nothing.  Two or
+    more run the chunked kernel: the tail is padded to the chunk with
     ``r = k = v = 0`` and ``lw = 0`` (w = 1), which leaves the output of
-    the real tokens and the carried state exact.  Short inputs (decode) use
-    one chunk of 16, the bf16 sublane tile.
+    the real tokens and the carried state exact; below the chunk the length
+    is padded to a multiple of 16, the bf16 sublane tile.
     """
     b, h, s, K = r.shape
     V = v.shape[-1]
-    L = chunk if s >= chunk else _round_up(s, 16)
-    pad = _round_up(s, L) - s
-    spans.report("wkv", real=b * s, computed=b * (s + pad))
-    if pad:
-        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
-        r, k, v, lw = (jnp.pad(t, widths) for t in (r, k, v, lw))
+    if s == 1:
+        spans.report("wkv", real=b, computed=b)
+        fn = functools.partial(wkv6_step_pallas, interpret=interpret)
+    else:
+        L = chunk if s >= chunk else _round_up(s, 16)
+        pad = _round_up(s, L) - s
+        spans.report("wkv", real=b * s, computed=b * (s + pad))
+        if pad:
+            widths = ((0, 0), (0, 0), (0, pad), (0, 0))
+            r, k, v, lw = (jnp.pad(t, widths) for t in (r, k, v, lw))
+        fn = functools.partial(wkv6_pallas, chunk=L, interpret=interpret)
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
-    fn = functools.partial(wkv6_pallas, chunk=L, interpret=interpret)
     spec = _heads_spec(r)
     u_spec = PartitionSpec(spec[1], None)
     y, s_out = _per_shard(fn, (r, k, v, lw, u, state),

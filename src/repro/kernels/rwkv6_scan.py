@@ -1,10 +1,21 @@
-"""Pallas TPU kernel for the chunked WKV6 recurrence (RWKV6 'Finch').
+"""Pallas TPU kernels for the WKV6 recurrence (RWKV6 'Finch').
 
-TPU adaptation of the (GPU, element-parallel) official kernel: instead of one
-thread per channel running the recurrence serially, the sequence is split
-into chunks of L tokens.  Within a chunk the work is (L, K)/(L, V) matmuls
-on the MXU; across chunks only the (K, V) state is carried — it lives in
-VMEM scratch and persists over the sequential chunk grid dimension.
+Two kernels, one ``name="wkv6"``; ``kernels.ops.pallas_wkv6`` picks by the
+input's length:
+
+  * one token (``wkv6_step_pallas``, the decode): the recurrence stepped
+    once, on the VPU.  A grid step takes one batch row and all its heads,
+    reads their state from HBM once and writes it once; the state query
+    r·S is a multiply and a sublane sum, in float32.
+  * two or more tokens (``wkv6_pallas``, the prefill): the chunked form
+    below.
+
+The chunked kernel is a TPU adaptation of the (GPU, element-parallel)
+official kernel: instead of one thread per channel running the recurrence
+serially, the sequence is split into chunks of L tokens.  Within a chunk the
+work is (L, K)/(L, V) matmuls on the MXU; across chunks only the (K, V)
+state is carried — it lives in VMEM scratch and persists over the
+sequential chunk grid dimension.
 
     y_t = r_t · (S_{t-1} + diag(u) k_t v_t^T)
     S_t = diag(w_t) S_{t-1} + k_t v_t^T         with 0 < w < 1
@@ -16,7 +27,7 @@ Every exponential computed here has exponent ≤ 0 (decays multiply), so the
 chunked form is overflow-safe in f32 regardless of sequence length or decay
 strength.
 
-Everything inside the kernel is a 2-D op the TPU compiler lowers:
+Everything inside the chunked kernel is a 2-D op the TPU compiler lowers:
   * the exclusive cumsum of the log-decays is a strictly-lower-triangular
     (L, L) matmul, the chunk's total decay a sublane ``sum``;
   * the intra-chunk decay exp(sw_t - sw_j - lw_j) depends on (t, j, channel),
@@ -26,7 +37,7 @@ Everything inside the kernel is a 2-D op the TPU compiler lowers:
     inclusive-cumsum tiles (VMEM scratch, dynamic sublane index) and column c
     of r and sw (a one-hot lane reduction).
 
-grid = (batch, heads, n_chunks); chunk dim is innermost/sequential.
+Chunked grid = (batch, heads, n_chunks); chunk dim is innermost/sequential.
 Blocks: r/k/lw (1, 1, L, K), v (1, 1, L, V), u (1, 1, K) per head,
 state (1, 1, K, V); scratch: state (K, V) f32 + two (K, L) f32 tiles.
 """
@@ -170,4 +181,70 @@ def wkv6_pallas(r, k, v, lw, u, state=None, *, chunk: int = 64,
         name="wkv6",
         interpret=interpret,
     )(r, k, v, lw, u3, state)
+    return y, s_out
+
+
+def _wkv6_step_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
+                      y_ref, sout_ref, *, K: int):
+    r = r_ref[0].astype(jnp.float32)               # (H, 1, K)
+    k = k_ref[0].astype(jnp.float32)               # (H, 1, K)
+    v = v_ref[0].astype(jnp.float32)               # (H, 1, V)
+    lw = lw_ref[0]                                 # (H, 1, K) log-decay
+    u = u_ref[...].astype(jnp.float32)             # (H, 1, K)
+    S = s0_ref[0]                                  # (H, K, V) f32
+
+    # a channel row (H, 1, K) as a sublane column (H, K, 1): one-hot lane sum
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
+           ).astype(jnp.float32)
+
+    def column(x):
+        return jnp.sum(eye * x, axis=2, keepdims=True)
+
+    r_col, k_col = column(r), column(k)
+    # y = r·S + (Σ_c r_c u_c k_c) v, the state query a sublane sum
+    y = jnp.sum(r_col * S, axis=1, keepdims=True)
+    y += jnp.sum(r * u * k, axis=2, keepdims=True) * v
+    # S' = diag(w) S + kᵀ v
+    sout_ref[0] = jnp.exp(column(lw)) * S + k_col * v
+    y_ref[0] = y.astype(y_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def wkv6_step_pallas(r, k, v, lw, u, state, *, interpret: bool):
+    """One token of the recurrence: r, k, lw (b, h, 1, K) (lw the log-decay
+    in float32), v (b, h, 1, V), u (h, K), state (b, h, K, V) f32.  Each
+    grid step takes one batch row and all its heads, reading the state
+    once and writing it once.
+    Returns (y (b, h, 1, V), new state (b, h, K, V) f32)."""
+    b, h, s, K = r.shape
+    V = v.shape[-1]
+    if s != 1:
+        raise ValueError(f"the step kernel takes one token, not {s}")
+    u3 = u.reshape(h, 1, K)
+    row = lambda ib: (ib, 0, 0, 0)
+    y, s_out = pl.pallas_call(
+        functools.partial(_wkv6_step_kernel, K=K),
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, h, 1, K), row),
+            pl.BlockSpec((1, h, 1, K), row),
+            pl.BlockSpec((1, h, 1, V), row),
+            pl.BlockSpec((1, h, 1, K), row),
+            pl.BlockSpec((h, 1, K), lambda ib: (0, 0, 0)),
+            pl.BlockSpec((1, h, K, V), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, h, 1, V), row),
+            pl.BlockSpec((1, h, K, V), row),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, 1, V), v.dtype),
+            jax.ShapeDtypeStruct((b, h, K, V), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        name="wkv6",
+        interpret=interpret,
+    )(r, k, v, lw.astype(jnp.float32), u3, state)
     return y, s_out

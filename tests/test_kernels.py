@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import pallas_attention, pallas_wkv6, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm_pallas
-from repro.kernels.rwkv6_scan import wkv6_pallas
+from repro.kernels.rwkv6_scan import wkv6_pallas, wkv6_step_pallas
 from repro.models.attention import lax_flash_attention, naive_attention
 from repro.models.ssm import wkv6_chunked, wkv6_sequential
 
@@ -144,10 +144,55 @@ def test_wkv6_pallas_with_initial_state():
     np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
 
 
+def _wkv6_inputs(seed, b, h, s, K, dtype=jnp.float32):
+    """r, k, v in ``dtype``, log-decay, bonus u and a random f32 state."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    r, k, v = (jax.random.normal(ks[i], (b, h, s, K)).astype(dtype)
+               for i in range(3))
+    lw = _log_decay(ks[3], (b, h, s, K))
+    u = jax.random.normal(ks[4], (h, K)) * 0.1
+    s0 = jax.random.normal(ks[5], (b, h, K, K), jnp.float32)
+    return r, k, v, lw, u, s0
+
+
+@pytest.mark.parametrize("b,h,K,dtype", [
+    (2, 3, 16, jnp.float32),
+    (16, 32, 64, jnp.float32),      # rwkv6-1.6b decode over 16 slots
+    (2, 4, 64, jnp.bfloat16),
+], ids=["small", "rwkv6-decode", "bf16"])
+def test_wkv6_step_vs_ref(b, h, K, dtype):
+    """One token from a random state: the step kernel against the
+    sequential recurrence."""
+    args = _wkv6_inputs(10, b, h, 1, K, dtype)
+    y, S = wkv6_step_pallas(*args, interpret=True)
+    ye, Se = ref.wkv6_ref(*args)
+    assert y.shape == (b, h, 1, K) and y.dtype == dtype
+    np.testing.assert_allclose(y.astype(jnp.float32), ye.astype(jnp.float32),
+                               atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
+
+
+def test_wkv6_step_chained_matches_ref_over_64_tokens():
+    """64 single-token calls, each handed the state the last returned, give
+    the recurrence over the same 64 tokens."""
+    r, k, v, lw, u, s0 = _wkv6_inputs(11, 2, 3, 64, 16)
+    S, ys = s0, []
+    for t in range(64):
+        tok = lambda x: x[:, :, t:t + 1]
+        y, S = wkv6_step_pallas(tok(r), tok(k), tok(v), tok(lw), u, S,
+                                interpret=True)
+        ys.append(y)
+    ye, Se = ref.wkv6_ref(r, k, v, lw, u, s0)
+    np.testing.assert_allclose(jnp.concatenate(ys, axis=2), ye,
+                               atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(S, Se, atol=2e-4, rtol=2e-4)
+
+
 @pytest.mark.parametrize("s", [1, 50, 100])
 def test_pallas_wkv6_pads_any_length_exactly(s):
-    """The wrapper pads the tail (r = k = v = 0, lw = 0) instead of falling
-    back: output and carried state match the recurrence at any length."""
+    """One token runs the step kernel; longer inputs are padded at the tail
+    (r = k = v = 0, lw = 0) instead of falling back: output and carried
+    state match the recurrence at any length."""
     b, h, K = 1, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(9), 6)
     r = jax.random.normal(ks[0], (b, h, s, K))

@@ -7,6 +7,8 @@ the repo serves and checks that the compiled program holds the kernel
 is described only inside a fixture (never at import), so one test worker
 loads the TPU library and the others collect the same tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -45,21 +47,26 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("b,s", [
-    (1, 512),     # rwkv6-1.6b prefill, 8 chunks of 64
-    (1, 50),      # a prompt length the wrapper pads to the chunk
-    (4, 1),       # one decode step over 4 slots
+@pytest.mark.parametrize("b,s,act", [
+    (1, 512, jnp.bfloat16),     # rwkv6-1.6b prefill, 8 chunks of 64
+    (1, 50, jnp.bfloat16),      # a prompt length the wrapper pads to the chunk
+    (4, 1, jnp.bfloat16),       # one decode step over 4 slots
+    (16, 1, jnp.float32),       # rwkv6-1.6b.gen decode: 16 slots, f32
 ])
-def test_wkv6_compiles_at_rwkv6_widths(one_chip, b, s):
+def test_wkv6_compiles_at_rwkv6_widths(one_chip, b, s, act):
     h, K = 32, 64                   # d_model 2048, head size 64
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    rkv = [sd((b, h, s, K), jnp.bfloat16)] * 3
+    rkv = [sd((b, h, s, K), act)] * 3
     lw = sd((b, h, s, K), jnp.float32)      # log-decay, float32
     args = rkv + [lw, sd((h, K), jnp.bfloat16), sd((b, h, K, K), jnp.float32)]
     text = _compiled_text(
         lambda r, k, v, lw, u, st: pallas_wkv6(r, k, v, lw, u, st,
                                                interpret=False), *args)
     assert "tpu_custom_call" in text
+    if s == 1:      # the step kernel: y at the token's own length, no pad
+        dt = "f32" if act == jnp.float32 else "bf16"
+        assert re.search(rf"%wkv6[.\d]* = \({dt}\[{b},32,1,64\]", text)
+        assert " pad(" not in text
 
 
 @pytest.mark.parametrize("hq,hkv,s", [

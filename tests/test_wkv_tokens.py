@@ -23,22 +23,22 @@ def _engine(arch, **variants):
                          max_seq=256, prefill_buckets=(64,))
 
 
-@pytest.mark.parametrize("impl,pad_to", [
-    (functools.partial(pallas_wkv6, interpret=True), 16),   # the chip's
-    (wkv6_chunked, 1),
+@pytest.mark.parametrize("impl,pads_prefill", [
+    (functools.partial(pallas_wkv6, interpret=True), True),   # the chip's
+    (wkv6_chunked, False),
 ], ids=["pallas", "chunked-lax"])
-def test_rwkv6_decode_and_prefill(impl, pad_to):
+def test_rwkv6_decode_and_prefill(impl, pads_prefill):
     engine = _engine("rwkv6-1.6b", wkv_impl=impl)
     engine.lower_decode()
-    # one token per slot and layer; the Pallas kernel computes a chunk of 16
-    assert engine.wkv_tokens == {"decode": {
-        "real": SLOTS, "computed": SLOTS * pad_to}}
+    # one token per slot and layer, computed as one: nothing is padded
+    assert engine.wkv_tokens == {"decode": {"real": SLOTS,
+                                            "computed": SLOTS}}
     # a recurrent model prefills at the prompt's exact length; the Pallas
     # wrapper pads to 16 below its 64-token chunk, to the chunk above
     for n, padded in ((23, 32), (100, 128)):
         engine.lower_prefill(list(range(1, n + 1)))
         assert engine.wkv_tokens["prefill"] == {
-            "real": n, "computed": padded if pad_to > 1 else n}
+            "real": n, "computed": padded if pads_prefill else n}
 
 
 def test_phi4_has_no_entry():
