@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from .. import spans
 from ..models.sharding import current_plan
 from .flash_attention import flash_attention
 from .rmsnorm import rmsnorm_pallas
@@ -102,12 +101,10 @@ def pallas_wkv6(r, k, v, lw, u, state=None, *, interpret: bool,
     b, h, s, K = r.shape
     V = v.shape[-1]
     if s == 1:
-        spans.report("wkv", real=b, computed=b)
         fn = functools.partial(wkv6_step_pallas, interpret=interpret)
     else:
         L = chunk if s >= chunk else _round_up(s, 16)
         pad = _round_up(s, L) - s
-        spans.report("wkv", real=b * s, computed=b * (s + pad))
         if pad:
             widths = ((0, 0), (0, 0), (0, pad), (0, 0))
             r, k, v, lw = (jnp.pad(t, widths) for t in (r, k, v, lw))

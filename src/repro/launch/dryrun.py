@@ -78,11 +78,6 @@ def input_specs(cfg, shape: ShapeSpec, entry: Dict[str, Any]
             "tokens": jax.ShapeDtypeStruct((B, S), i32),
             "positions": pos_struct(B, S),
         }
-        if cfg.family == "audio-lm":
-            batch["embeds"] = jax.ShapeDtypeStruct((B, S, cfg.d_model), f32)
-        if cfg.family == "vlm-lm":
-            batch["vis_embeds"] = jax.ShapeDtypeStruct(
-                (B, min(64, S), cfg.d_model), f32)
         return {"params": params, "batch": batch, "cache": cache}
 
     # decode: one new token with a seq_len-deep cache

@@ -198,9 +198,6 @@ def main(argv=None) -> int:
     print(f"{len(resp)} responses, {toks} tokens in {dt:.1f}s "
           f"({toks/dt:.1f} tok/s, {engine._ticks} engine ticks)")
     print(compiles.summary())
-    print("decode cache leaves:", engine.decode_cache_paths)
-    if engine.wkv_tokens:
-        print("wkv tokens per layer, real / computed:", engine.wkv_tokens)
     return 0
 
 

@@ -14,7 +14,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import spans
 from .common import P, SpecTree, rms_norm
 from .sharding import shard
 
@@ -30,7 +29,6 @@ from .sharding import shard
 def wkv6_sequential(r, k, v, lw, u, state=None):
     b, h, s, K = r.shape
     V = v.shape[-1]
-    spans.report("wkv", real=b * s, computed=b * s)
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
     rf, kf, vf = (t.astype(jnp.float32) for t in (r, k, v))
@@ -56,7 +54,6 @@ def wkv6_chunked(r, k, v, lw, u, state=None, chunk: int = 32):
     V = v.shape[-1]
     if s % chunk or s <= chunk:
         return wkv6_sequential(r, k, v, lw, u, state)
-    spans.report("wkv", real=b * s, computed=b * s)
     if state is None:
         state = jnp.zeros((b, h, K, V), jnp.float32)
     n = s // chunk

@@ -70,6 +70,9 @@ class Model:
     stacks: Tuple[Stack, ...]
     specs: SpecTree                             # full stacked param tree
     mtp: bool = False
+    # layers carry recurrent state, which would integrate a prompt's
+    # padding: such a model prefills at the prompt's exact length
+    recurrent: bool = False
 
     # -- params ---------------------------------------------------------
     def init(self, key, dtype=None):
@@ -102,11 +105,8 @@ class Model:
         return axes_tree(self.cache_specs(batch, max_seq))
 
     # -- forward ----------------------------------------------------------
-    def backbone(self, params, x, positions, cache=None, cache_pos=0,
-                 cache_paths=None):
-        """x: (b, s, d) embeddings → (h, new_cache, aux).  ``cache_paths``,
-        a dict, counts the cache leaves written in place ("in_place") and
-        those the scan hands back whole ("scanned")."""
+    def backbone(self, params, x, positions, cache=None, cache_pos=0):
+        """x: (b, s, d) embeddings → (h, new_cache, aux)."""
         aux = jnp.zeros((), jnp.float32)
         new_cache: Dict[str, Any] = {}
         with matmul_precision(self.cfg):
@@ -130,20 +130,22 @@ class Model:
                 (x, aux), c_out = jax.lax.scan(
                     scan_fn, (x, aux), (stacked_params, st_cache))
                 if st_cache is not None:
-                    new_cache[st.name] = _write_back(st_cache, c_out, cache_pos,
-                                                     cache_paths)
+                    new_cache[st.name] = _write_back(st_cache, c_out,
+                                                     cache_pos)
         return x, (new_cache if cache is not None else None), aux
 
-    def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0,
-                  cache_paths=None):
+    def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0):
         h, new_cache, aux = self.backbone(params, embeds, positions,
-                                          cache, cache_pos, cache_paths)
+                                          cache, cache_pos)
+        return self._head(params, h), h, new_cache, aux
+
+    def _head(self, params, h):
+        """Final norm and LM head: (b, s, d) hidden states → f32 logits."""
         h = apply_norm(params["final_norm"], h, self.cfg,
                        self.variants.rms_norm)
         logits = lm_logits(params.get("head", {}), params["embed"], h,
                            self.cfg)
-        logits = shard(logits, "act_batch", "act_seq", "act_vocab")
-        return logits, h, new_cache, aux
+        return shard(logits, "act_batch", "act_seq", "act_vocab")
 
     # -- embedding frontends ----------------------------------------------
     def embed(self, params, batch):
@@ -201,51 +203,59 @@ class Model:
     _mtp_block_apply: Callable = None
 
     # -- serving -------------------------------------------------------------
-    def prefill(self, params, batch, cache):
-        """Prefill computes the LM head for the LAST position only — the
-        full-sequence vocab projection (b, s, V) is pure waste at prefill
-        (s=32k × vocab=256k would dwarf the backbone's own traffic)."""
-        e = self.embed(params, batch)
-        positions = batch["positions"]
-        h, cache, _ = self.backbone(params, e, positions, cache, 0)
-        h_last = apply_norm(params["final_norm"], h[:, -1:, :], self.cfg,
-                            self.variants.rms_norm)
-        logits = lm_logits(params.get("head", {}), params["embed"], h_last,
-                           self.cfg)
-        return logits[:, 0, :], cache
-
-    def decode_step(self, params, tokens, positions, cache, cache_pos,
-                    cache_paths=None):
-        """tokens: (b, 1); positions: (b, 1) or (3, b, 1)."""
-        batch = {"tokens": tokens}
+    def _embed_served(self, params, tokens, positions):
+        """Served tokens (b, s) → embeddings, through the table; an
+        ``audio-lm`` adds sinusoidal embeddings of its (b, s) positions
+        (``embed``, the training front end, takes precomputed frame
+        embeddings instead)."""
+        e = embed_tokens(params["embed"], tokens, self.cfg)
         if self.cfg.family == "audio-lm":
-            # decode feeds embeddings: frontends decode via embedding table
-            e = params["embed"]["tok"][tokens]
-            pos2d = positions if positions.ndim == 2 else positions[0]
-            e = e + sinusoidal_pos(pos2d, self.cfg.d_model).astype(e.dtype)
-        else:
-            e = embed_tokens(params["embed"], tokens, self.cfg)
-        logits, _, cache, _ = self.logits_fn(params, e, positions, cache,
-                                             cache_pos, cache_paths)
+            e = e + sinusoidal_pos(positions, self.cfg.d_model).astype(e.dtype)
+        return e
+
+    def _rope_positions(self, positions):
+        """(b, s) positions in the layout the rotary embedding takes:
+        M-RoPE's (3, b, s), one position on all three axes, where the
+        config asks for it.  (3, b, s) positions pass as they are."""
+        if self.cfg.mrope_sections and positions.ndim == 2:
+            return jnp.broadcast_to(positions, (3,) + positions.shape)
+        return positions
+
+    def prefill(self, params, batch, cache, length=None):
+        """Prefill ``batch["tokens"]`` (b, s) into ``cache``: (next-token
+        logits (b, vocab), cache).  The backbone runs over all s positions;
+        the final norm and the LM head run on row ``length - 1`` alone, the
+        prompt's last real token (``length`` defaults to s: no padding).
+        A full-sequence vocab projection (b, s, V) would be pure waste.
+        Every family prefills from tokens alone, as it decodes: the
+        training front end's ``embeds`` and ``vis_embeds`` are not read."""
+        positions = batch["positions"]
+        e = self._embed_served(params, batch["tokens"], positions)
+        h, cache, _ = self.backbone(params, e, self._rope_positions(positions),
+                                    cache, 0)
+        length = h.shape[1] if length is None else length
+        h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
+        return self._head(params, h_last)[:, 0, :], cache
+
+    def decode_step(self, params, tokens, positions, cache, cache_pos):
+        """tokens: (b, 1); positions: (b, 1) or (3, b, 1)."""
+        e = self._embed_served(params, tokens, positions)
+        logits, _, cache, _ = self.logits_fn(
+            params, e, self._rope_positions(positions), cache, cache_pos)
         return logits[:, -1, :], cache
 
 
-def _write_back(cache, out, cache_pos, paths):
+def _write_back(cache, out, cache_pos):
     """A stack's cache after its scan: each ``KVWrite`` in the scan's ys
     ((layers, b, kv, 1, hd) leaves) is written into its cache at
     ``cache_pos``; every other subtree of ys is the new cache as it is."""
     if isinstance(out, KVWrite):
-        new = {"k": write_token(cache["k"], out.k, cache_pos),
-               "v": write_token(cache["v"], out.v, cache_pos)}
-        path = "in_place"
-    elif isinstance(out, dict):
-        return {name: _write_back(cache[name], o, cache_pos, paths)
+        return {"k": write_token(cache["k"], out.k, cache_pos),
+                "v": write_token(cache["v"], out.v, cache_pos)}
+    if isinstance(out, dict):
+        return {name: _write_back(cache[name], o, cache_pos)
                 for name, o in out.items()}
-    else:
-        new, path = out, "scanned"
-    if paths is not None:
-        paths[path] = paths.get(path, 0) + len(jax.tree.leaves(new))
-    return new
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -462,11 +472,11 @@ def _hybrid_stacks(cfg, v: Variants) -> Tuple[Stack, ...]:
 def build_model(cfg, variants: Optional[Variants] = None) -> Model:
     v = variants or Variants()
     if cfg.family == "ssm-lm":
-        stacks = _rwkv_stacks(cfg, v)
+        stacks, recurrent = _rwkv_stacks(cfg, v), True
     elif cfg.family == "hybrid-lm":
-        stacks = _hybrid_stacks(cfg, v)
+        stacks, recurrent = _hybrid_stacks(cfg, v), True
     else:
-        stacks = _uniform_stacks(cfg, v)
+        stacks, recurrent = _uniform_stacks(cfg, v), False
 
     specs: SpecTree = {"embed": embed_spec(cfg),
                        "final_norm": norm_spec(cfg)}
@@ -488,6 +498,6 @@ def build_model(cfg, variants: Optional[Variants] = None) -> Model:
             return _apply(p, x, positions, None, 0)
 
     m = Model(cfg=cfg, variants=v, stacks=tuple(stacks), specs=specs,
-              mtp=cfg.mtp)
+              mtp=cfg.mtp, recurrent=recurrent)
     m._mtp_block_apply = mtp_apply
     return m

@@ -2,8 +2,9 @@
 
 The engine owns ``num_slots`` cache slots.  Each engine tick:
   1. admit — free slots are filled from the request queue; the prompt is
-     prefilled (padded to a fixed bucket so the compiled prefill is reused)
-     and its cache scattered into the slot;
+     prefilled (``Model.prefill``, padded to a fixed bucket so the compiled
+     prefill is reused; a recurrent model prefills at the exact length) and
+     its cache scattered into the slot;
   2. decode — ONE fused decode step advances *all* live slots together,
      each at its own depth (vector ``cache_pos``);
   3. retire — slots that hit EOS/max_tokens emit a finished response.
@@ -26,7 +27,7 @@ import dataclasses
 import math
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -96,19 +97,10 @@ class ServingEngine:
         self._next_tokens = np.zeros(num_slots, np.int32)
         self._key = jax.random.PRNGKey(rng_seed)
         self._ticks = 0
-        # cache leaves the decode step writes in place or gets back whole
-        # from the layer scan; counted each time the step is traced
-        self.decode_cache_paths: Optional[Dict[str, int]] = None
-        # per program ("prefill": the last prefill traced, "decode"): the
-        # tokens one layer's WKV call is handed in one run ("real") and
-        # those it computes after padding ("computed"); counted each time
-        # the program is traced, no entry for a model without WKV
-        self.wkv_tokens: Dict[str, Dict[str, int]] = {}
 
         # jitted single-slot prefill (per bucket), slot insert and fused
         # decode; the cache keeps the plan's shardings across all three
         self._prefill = jax.jit(self._prefill_impl,
-                                static_argnames=("bucket",),
                                 out_shardings=(None, cache_shardings(1)))
         self._insert = jax.jit(self._insert_impl, out_shardings=cache_sh,
                                donate_argnums=(0,))
@@ -117,36 +109,15 @@ class ServingEngine:
                                donate_argnums=(1,))
 
     # -- jitted bodies ------------------------------------------------------
-    def _prefill_impl(self, params, tokens, length, bucket: int):
+    def _prefill_impl(self, params, tokens, length):
         """tokens: (1, bucket); length: scalar prompt length.
         Returns (next_token_logits (1, v), cache_b1)."""
-        with use_plan(self.plan):
-            return self._prefill_body(params, tokens, length, bucket)
-
-    def _prefill_body(self, params, tokens, length, bucket: int):
         m = self.model
-        cache = m.init_cache(1, self.max_seq)
-        pos = jnp.arange(bucket, dtype=jnp.int32)[None]
-        if self.cfg.mrope_sections:
-            pos3 = jnp.broadcast_to(pos, (3, 1, bucket))
-            batch = {"tokens": tokens, "positions": pos3}
-        else:
-            batch = {"tokens": tokens, "positions": pos}
-        if self.cfg.family == "audio-lm":
-            # serve path embeds codebook tokens via the embedding table
-            from .models.common import sinusoidal_pos
-            e = params["embed"]["tok"][tokens]
-            e = e + sinusoidal_pos(pos, self.cfg.d_model).astype(e.dtype)
-        else:
-            e = m.embed(params, batch)
-        with spans.TraceCount() as count:
-            logits, _, cache, _ = m.logits_fn(params, e, batch["positions"],
-                                              cache, 0)
-        self._note_wkv("prefill", count)
-        last = jnp.take_along_axis(
-            logits, (length - 1)[None, None, None].astype(jnp.int32)
-            if jnp.ndim(length) == 0 else length[:, None, None], axis=1)
-        return last[:, 0, :], cache
+        with use_plan(self.plan):
+            cache = m.init_cache(1, self.max_seq)
+            pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
+            return m.prefill(params, {"tokens": tokens, "positions": pos},
+                             cache, length)
 
     @staticmethod
     def _insert_impl(full_cache, cache1, slot):
@@ -157,27 +128,15 @@ class ServingEngine:
                 full, one.astype(full.dtype), slot, axis=1),
             full_cache, cache1)
 
-    def _decode_impl(self, params, cache, tokens, positions, live, key,
-                     temps):
-        """tokens: (slots,); positions: (slots,); live: (slots,) bool."""
+    def _decode_impl(self, params, cache, tokens, positions, key, temps):
+        """tokens: (slots,); positions: (slots,)."""
         with use_plan(self.plan):
             return self._decode_body(params, cache, tokens, positions, key,
                                      temps)
 
     def _decode_body(self, params, cache, tokens, positions, key, temps):
-        m = self.model
-        toks = tokens[:, None]
-        pos = positions[:, None]
-        if self.cfg.mrope_sections:
-            pos_in = jnp.broadcast_to(pos, (3,) + pos.shape)
-        else:
-            pos_in = pos
-        paths = {"in_place": 0, "scanned": 0}
-        with spans.TraceCount() as count:
-            logits, new_cache = m.decode_step(params, toks, pos_in, cache,
-                                              positions, cache_paths=paths)
-        self.decode_cache_paths = paths
-        self._note_wkv("decode", count)
+        logits, new_cache = self.model.decode_step(
+            params, tokens[:, None], positions[:, None], cache, positions)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         gumbel = -jnp.log(-jnp.log(
             jax.random.uniform(key, logits.shape, minval=1e-9, maxval=1.0)))
@@ -188,10 +147,6 @@ class ServingEngine:
         # dead slots must not corrupt their cache position: they decode into
         # position max_seq-1 and their token is ignored on the host.
         return next_tok, new_cache
-
-    def _note_wkv(self, program: str, count: spans.TraceCount) -> None:
-        if "wkv" in count.totals:
-            self.wkv_tokens[program] = count.totals["wkv"]
 
     # -- public API -----------------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 32,
@@ -205,45 +160,40 @@ class ServingEngine:
         return rid
 
     def _bucket_for(self, n: int) -> int:
-        if self.cfg.family in ("ssm-lm", "hybrid-lm"):
-            # recurrent state must not integrate padding junk: exact-length
-            # prefill (one compile per distinct prompt length)
+        if self.model.recurrent:    # one compile per distinct length
             return n
         for b in self.prefill_buckets:
             if n <= b:
                 return b
         return self.prefill_buckets[-1]
 
-    def _prefill_args(self, prompt: Sequence[int]) -> Tuple[tuple, dict]:
-        """The (args, kwargs) the jitted prefill takes for ``prompt``: its
+    def _prefill_args(self, prompt: Sequence[int]) -> tuple:
+        """The arguments the jitted prefill takes for ``prompt``: its
         bucket-padded tokens and its length (clipped to the bucket)."""
         n = len(prompt)
         bucket = self._bucket_for(n)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :n] = np.asarray(prompt, np.int32)[:bucket]
-        return ((self.params, jnp.asarray(toks),
-                 jnp.asarray(min(n, bucket), jnp.int32)),
-                {"bucket": bucket})
+        return (self.params, jnp.asarray(toks),
+                jnp.asarray(min(n, bucket), jnp.int32))
 
     def prefill(self, prompt: Sequence[int]):
         """Prefill ``prompt`` alone: (next-token logits (1, vocab), the
         batch-1 cache, the number of prompt tokens kept)."""
-        args, kw = self._prefill_args(prompt)
-        logits, cache1 = self._prefill(*args, **kw)
+        args = self._prefill_args(prompt)
+        logits, cache1 = self._prefill(*args)
         return logits, cache1, int(args[2])
 
     def lower_prefill(self, prompt: Sequence[int]) -> jax.stages.Lowered:
         """The prefill program ``prompt`` runs, lowered (not run)."""
-        args, kw = self._prefill_args(prompt)
-        return self._prefill.lower(*args, **kw)
+        return self._prefill.lower(*self._prefill_args(prompt))
 
     def lower_decode(self) -> jax.stages.Lowered:
         """The fused decode step over all slots, lowered (not run)."""
         n = self.num_slots
         return self._decode.lower(
             self.params, self.cache, jnp.zeros(n, jnp.int32),
-            jnp.zeros(n, jnp.int32), jnp.ones(n, bool), self._key,
-            jnp.zeros(n, jnp.float32))
+            jnp.zeros(n, jnp.int32), self._key, jnp.zeros(n, jnp.float32))
 
     def _admit(self) -> None:
         for slot in range(self.num_slots):
@@ -299,8 +249,7 @@ class ServingEngine:
             self._key, sub = jax.random.split(self._key)
             next_tok, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(self._next_tokens),
-                jnp.asarray(positions), jnp.asarray(live), sub,
-                jnp.asarray(temps))
+                jnp.asarray(positions), sub, jnp.asarray(temps))
         with spans.span(spans.TOKEN_SYNC):
             next_tok = np.asarray(jax.device_get(next_tok))
             for slot in range(self.num_slots):

@@ -16,15 +16,12 @@ The serving engine opens the spans in :data:`NAMES`, and no others:
                      temperatures, key split) to its dispatch
   engine.token_sync  the ``device_get`` of the step's tokens and the
                      per-slot bookkeeping after it
-
-:class:`TraceCount` sums what traced code reports with :func:`report` while
-a program is traced: the work a program does, read from its shapes.
 """
 from __future__ import annotations
 
 import collections
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import jax
 
@@ -44,7 +41,6 @@ EVENTS = {"/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
 class _Open(threading.local):
     def __init__(self):
         self.names: List[str] = []
-        self.count: Optional["TraceCount"] = None
 
 
 _open = _Open()
@@ -126,31 +122,3 @@ class CompileCounter:
 def _seconds(kinds: Dict[str, List]) -> float:
     return kinds["lower"][1] + kinds["compile"][1]
 
-
-class TraceCount:
-    """``with TraceCount() as c:`` sums the amounts that code traced inside
-    it reports with :func:`report`: ``c.totals[name][key]``.  It counts at
-    trace time, once per traced call, so a report inside a layer scan's body
-    counts one layer."""
-
-    def __init__(self):
-        self.totals: Dict[str, Dict[str, int]] = {}
-        self._outer: Optional[TraceCount] = None
-
-    def __enter__(self):
-        self._outer, _open.count = _open.count, self
-        return self
-
-    def __exit__(self, *exc):
-        _open.count = self._outer
-
-
-def report(name: str, **amounts: int) -> None:
-    """Add ``amounts`` under ``name`` in the innermost :class:`TraceCount`
-    open on this thread, if any."""
-    count = _open.count
-    if count is None:
-        return
-    entry = count.totals.setdefault(name, {})
-    for key, n in amounts.items():
-        entry[key] = entry.get(key, 0) + n

@@ -61,8 +61,6 @@ def test_decode_smoke(arch_id, built):
     if cfg.mrope_sections:
         pos = jnp.broadcast_to(pos, (3, b, s))
     batch = {"tokens": toks, "positions": pos}
-    if cfg.family == "audio-lm":
-        batch["embeds"] = jnp.zeros((b, s, cfg.d_model), jnp.float32)
     logits, cache = inst.entry["prefill"](params, batch, cache)
     assert logits.shape == (b, cfg.vocab)
     assert bool(jnp.all(jnp.isfinite(logits)))

@@ -39,7 +39,6 @@ def test_decode_writes_the_donated_cache_in_place(service, smoke_mesh):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < cache_bytes
     assert mem.alias_size_in_bytes == cache_bytes
-    assert eng.decode_cache_paths == {"in_place": 2, "scanned": 0}
 
 
 def _write_then_attend(params, x, cfg, *, positions, cache=None,
@@ -106,8 +105,6 @@ def test_in_place_cache_matches_write_then_attend(service, smoke_mesh,
                 np.asarray(got.cache["blocks"][name]), want, rtol=1e-5,
                 atol=1e-5 * np.abs(want).max(), err_msg=f"step {step} {name}")
     assert got.slot_req[dead] is None and got.slot_pos[0] != got.slot_pos[1]
-    assert got.decode_cache_paths == {"in_place": 2, "scanned": 0}
-    assert ref.decode_cache_paths == {"in_place": 0, "scanned": 2}
     for name in ("k", "v"):
         rows = np.asarray(got.cache["blocks"][name])[:, dead]
         assert not rows[:, :, :max_seq - 1].any()

@@ -72,6 +72,36 @@ def test_continuous_batching_matches_isolated_decode(arch_id, service,
         assert got[i] == exp, f"{arch_id} request {i}: {got[i]} != {exp}"
 
 
+@pytest.mark.parametrize("arch_id", [
+    "phi4-mini-3.8b",       # dense: an 11-token prompt in a 16 bucket
+    "qwen2-vl-2b",          # M-RoPE: the model lays out (b, s) positions
+    "musicgen-medium",      # audio-lm: tokens through the embedding table
+    "rwkv6-1.6b",           # recurrent: prefilled at the exact length
+])
+def test_engine_prefill_matches_the_entry_on_the_unpadded_prompt(
+        arch_id, service, smoke_mesh):
+    """The engine prefills through the serve entry's ``Model.prefill``: at a
+    padded bucket it gives the next-token logits of the unpadded prompt."""
+    cfg = ARCHS[arch_id].reduced()
+    inst = LazyBuilder(service).build(
+        PreBuilder(service).prebuild(cfg, entrypoint="serve"), cpu_smoke(),
+        mesh=smoke_mesh)
+    params = inst.model.init(jax.random.PRNGKey(0))
+    engine = inst.entry["make_engine"](params, num_slots=1, max_seq=32,
+                                       prefill_buckets=(16,))
+    prompt = np.random.default_rng(2).integers(1, cfg.vocab, 11).tolist()
+    got, _, n_kept = engine.prefill(prompt)
+    batch = {"tokens": jnp.asarray([prompt], jnp.int32),
+             "positions": jnp.arange(len(prompt), dtype=jnp.int32)[None]}
+    want, _ = inst.entry["prefill"](params, batch,
+                                    inst.model.init_cache(1, 32))
+    assert n_kept == len(prompt)
+    assert engine._bucket_for(len(prompt)) == (
+        len(prompt) if inst.model.recurrent else 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_engine_respects_max_new_tokens(service, smoke_mesh):
     cfg = ARCHS["starcoder2-3b"].reduced()
     pb = PreBuilder(service)
