@@ -10,12 +10,16 @@ Families
   vlm-lm     : dense decoder with M-RoPE + injected patch embeddings (stub)
 
 Layers are stacked and driven by ``lax.scan`` (small HLO, fast compile, the
-MaxText idiom).  Caches go into the scan as xs.  In a decode step a
-full-length GQA K/V cache is only read there: each layer hands back its new
-token (``KVWrite``) as ys, and after the scan each token is written into the
-stacked cache at its slot's position, so a donated cache is updated in
-place.  Everything else (prefill, sliding-window ring caches, MLA caches,
-recurrent states) comes back whole as ys.
+MaxText idiom).  In a prefill the caches go into the scan as xs and come
+back whole as ys.  In a step of one token (decode) nothing is copied whole:
+  * a full-length GQA K/V cache goes in as xs and is only read; each layer
+    hands back its new token (``KVWrite``) as ys, and after the scan each
+    token is written into the stacked cache at its slot's position;
+  * every cache a layer rewrites whole (RWKV and Mamba states, sliding-
+    window ring caches, MLA caches) rides the scan's carry: each layer reads
+    its slice of the stacked buffer and writes its new slice back into it.
+So a donated cache is updated in place.  What a layer returns (a
+``KVWrite`` or a whole leaf) decides the path.
 """
 from __future__ import annotations
 
@@ -119,19 +123,41 @@ class Model:
                     body = jax.checkpoint(body, policy=policy,
                                           static_argnums=())
                 st_cache = cache.get(st.name) if cache is not None else None
+                stacked_params = params[st.name]
+                has_cache, state = st_cache is not None, None
+                if has_cache and x.shape[1] == 1:
+                    whole, st_cache = _split_for_step(
+                        body, stacked_params, x, positions, st_cache,
+                        cache_pos)
+                    if whole is not None:
+                        state = (jnp.zeros((), jnp.int32), whole,
+                                 _layer(whole, 0))
 
                 def scan_fn(carry, xs, _body=body):
-                    h, a = carry
+                    h, a, state = carry
                     p, c = xs
+                    if state is not None:
+                        i, whole, now = state
+                        c = _merge(now, c)
                     h, c_new, a_l = _body(p, h, positions, c, cache_pos)
-                    return (h, a + a_l), c_new
+                    if state is not None:
+                        # write layer i's new state in place, then read
+                        # layer i + 1's (past the last layer the index
+                        # clamps): no read of the buffer is pending at the
+                        # write, so no compiler copies the buffer
+                        new, c_new = _split(c_new, c_new)
+                        whole = jax.tree.map(
+                            lambda s, n: jax.lax.dynamic_update_index_in_dim(
+                                s, n, i, 0), whole, new)
+                        state = (i + 1, whole, _layer(whole, i + 1))
+                    return (h, a + a_l, state), c_new
 
-                stacked_params = params[st.name]
-                (x, aux), c_out = jax.lax.scan(
-                    scan_fn, (x, aux), (stacked_params, st_cache))
-                if st_cache is not None:
-                    new_cache[st.name] = _write_back(st_cache, c_out,
-                                                     cache_pos)
+                (x, aux, state), c_out = jax.lax.scan(
+                    scan_fn, (x, aux, state), (stacked_params, st_cache))
+                if has_cache:
+                    new_cache[st.name] = _merge(
+                        state[1] if state is not None else None,
+                        _write_back(st_cache, c_out, cache_pos))
         return x, (new_cache if cache is not None else None), aux
 
     def logits_fn(self, params, embeds, positions, cache=None, cache_pos=0):
@@ -249,6 +275,8 @@ def _write_back(cache, out, cache_pos):
     """A stack's cache after its scan: each ``KVWrite`` in the scan's ys
     ((layers, b, kv, 1, hd) leaves) is written into its cache at
     ``cache_pos``; every other subtree of ys is the new cache as it is."""
+    if out is None:
+        return None
     if isinstance(out, KVWrite):
         return {"k": write_token(cache["k"], out.k, cache_pos),
                 "v": write_token(cache["v"], out.v, cache_pos)}
@@ -501,3 +529,42 @@ def build_model(cfg, variants: Optional[Variants] = None) -> Model:
               mtp=cfg.mtp, recurrent=recurrent)
     m._mtp_block_apply = mtp_apply
     return m
+
+
+def _split_for_step(body, stacked_params, x, positions, cache, cache_pos):
+    """A stack's cache in a step of one token, split by what a layer
+    (``body``) returns for it: (whole, written), the subtrees it hands back
+    whole, which ride the scan's carry, and those it hands back as a
+    ``KVWrite``, which stay xs.  The layer is traced for shapes only."""
+    layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                         (stacked_params, cache))
+    out = jax.eval_shape(
+        lambda p, c: body(p, x, positions, c, cache_pos)[1], *layer)
+    return _split(cache, out)
+
+
+def _split(tree, out):
+    """(whole, written): the subtrees of ``tree`` (a cache, or what a layer
+    returns for it) that the layer's output ``out`` hands back whole, and
+    those it hands back as a ``KVWrite``; None where there are none."""
+    if isinstance(out, KVWrite):
+        return None, tree
+    if isinstance(out, dict):
+        parts = {name: _split(tree[name], o) for name, o in out.items()}
+        whole = {n: w for n, (w, _) in parts.items() if w is not None}
+        written = {n: t for n, (_, t) in parts.items() if t is not None}
+        return whole or None, written or None
+    return tree, None
+
+
+def _layer(tree, i):
+    """Layer ``i`` of a stacked cache subtree."""
+    return jax.tree.map(
+        lambda s: jax.lax.dynamic_index_in_dim(s, i, keepdims=False), tree)
+
+
+def _merge(a, b):
+    """The union of two cache subtrees that ``_split`` took apart."""
+    if a is None or b is None:
+        return b if a is None else a
+    return {name: _merge(a.get(name), b.get(name)) for name in {**a, **b}}

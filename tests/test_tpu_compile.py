@@ -7,6 +7,7 @@ the repo serves and checks that the compiled program holds the kernel
 is described only inside a fixture (never at import), so one test worker
 loads the TPU library and the others collect the same tests.
 """
+import functools
 import re
 
 import jax
@@ -16,8 +17,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import ARCHS
 from repro.kernels import pallas_attention, pallas_rmsnorm, pallas_wkv6
 from repro.models.sharding import RULE_SETS, ShardingPlan, use_plan
+from repro.models.transformer import Variants, build_model
 
 
 @pytest.fixture(scope="module")
@@ -112,3 +115,37 @@ def test_attention_runs_per_shard_on_four_chips(topo):
     text = _compiled_text(attend, q, q, q)
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+def test_rwkv6_decode_updates_its_state_in_place(one_chip):
+    """rwkv6-1.6b's decode at full width, 16 slots, with the Pallas kernels:
+    the donated state is updated in place, with no whole-state copy and no
+    second state among the temporaries (a state scanned as xs and ys held
+    409.6 MB of them)."""
+    model = build_model(ARCHS["rwkv6-1.6b"], Variants(
+        wkv_impl=functools.partial(pallas_wkv6, interpret=False),
+        rms_norm=functools.partial(pallas_rmsnorm, interpret=False),
+        remat="none"))
+    on_chip = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(16, 2048)))
+    assert cache["blocks"]["wkv"].shape == (24, 16, 32, 64, 64)
+    slots = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+
+    def decode(params, cache, tokens, positions):
+        return model.decode_step(params, tokens[:, None], positions[:, None],
+                                 cache, positions)
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        on_chip(model.param_shapes()), cache, slots, slots).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    copies = [ln for ln in text.splitlines()
+              if re.search(r"= f32\[24,16,(32,64,64|2048)\]\S* copy", ln)]
+    assert not copies, copies[:2]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 20
+    # the whole state is aliased, as laid out on the chip: the wkv state's
+    # 64-wide minor dim padded to 128 lanes (402.7 MB), two shift states
+    assert mem.alias_size_in_bytes == (24 * 16 * 32 * 64 * 128
+                                       + 2 * 24 * 16 * 2048) * 4
