@@ -16,10 +16,12 @@ the reference kernels (naive attention, sequential WKV6, XLA RMSNorm) on the
 same parameters, the first greedy tokens, and a ``tpu_custom_call`` in the
 compiled prefill.
 
-Four chips: ``codeqwen1.5-7b`` cut to 8 layers, prefilled on one chip and on
-a (1, 4) ("data", "model") mesh from the same seed, logits compared; then
-the full-depth model serves requests on the four chips, and the bytes in use
-on every device show that it is spread.
+Four chips: ``codeqwen1.5-7b`` (32 query heads over 4 kv heads) cut to 8
+layers, prefilled on one chip and on a (1, 4) ("data", "model") mesh from
+the same seed, logits compared; then the full-depth model serves requests on
+the four chips, each holding 8 query heads and 1 kv head of every layer
+(about 3.63 GB of its 14.5 GB of bf16 weights), and the bytes in use on
+every device show that it is spread.
 
 The labelled lines are one smoke run, not metrics.  The last line is
 ``{"ok": true, "device": {...}}``.  Any failed check raises, so the exit

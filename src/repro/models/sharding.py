@@ -56,15 +56,18 @@ def fsdp_tp_rules(mesh_axes: Sequence[str]) -> Dict[str, AxisVal]:
 
 
 def decode_rules(mesh_axes: Sequence[str]) -> Dict[str, AxisVal]:
-    """Batched decode: KV cache sequence-sharded over 'model' (flash-decode —
-    GSPMD turns the seq-contracted attention einsum into partial softmax
-    sums + an all-reduce), batch over the data axes.  Sequence sharding
-    beats head sharding here because kv_heads rarely divides the model axis
-    while seq_len always does."""
+    """Batched decode, batch over the data axes.  The KV cache shards its
+    heads over 'model' where the kv heads divide that axis: each chip then
+    attends with its own query heads to its own kv heads, as the attention
+    weights are split, and nothing moves between chips but the all-reduce
+    after the output projection.  Where they do not divide it (kv_heads
+    rarely does at 16-way), the head dim is replicated and 'model' falls to
+    the sequence dim instead (flash-decode — GSPMD turns the seq-contracted
+    attention einsum into partial softmax sums + an all-reduce)."""
     r = fsdp_tp_rules(mesh_axes)
     b = _batch_axes(mesh_axes)
     r.update({
-        "cache_batch": b, "cache_seq": "model", "cache_heads": None,
+        "cache_batch": b, "cache_heads": "model", "cache_seq": "model",
     })
     return r
 

@@ -84,7 +84,7 @@ def test_full_config_matches_assignment(arch_id):
         "deepseek-v3-671b": (61, 7168, 128, 128, 129280),
         "dbrx-132b": (40, 6144, 48, 8, 100352),
         "gemma2-9b": (42, 3584, 16, 8, 256000),
-        "codeqwen1.5-7b": (32, 4096, 32, 32, 92416),
+        "codeqwen1.5-7b": (32, 4096, 32, 4, 92416),
         "phi4-mini-3.8b": (32, 3072, 24, 8, 200064),
         "starcoder2-3b": (30, 3072, 24, 2, 49152),
         "musicgen-medium": (48, 1536, 24, 24, 2048),

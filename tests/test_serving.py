@@ -167,11 +167,9 @@ _SHARDED_SCRIPT = textwrap.dedent("""
             tokens[shape] = sorted((r.rid, r.tokens)
                                    for r in engine.run_until_drained())
             if shape == (1, 4):
-                # the decode plan splits the KV cache on its sequence axis;
-                # rwkv's recurrent state has none and stays replicated
-                spread = (params, engine.cache) if cfg.family != "ssm-lm" \
-                    else (params,)
-                for tree in spread:
+                # the decode plan splits the KV cache, and rwkv's
+                # recurrent state, over their 4 heads
+                for tree in (params, engine.cache):
                     assert any(not x.sharding.is_fully_replicated
                                for x in jax.tree.leaves(tree)), arch
         assert tokens[(1,)] == tokens[(1, 4)], (arch, tokens)

@@ -62,6 +62,15 @@ def test_decode_rules_shard_cache_seq():
     assert s == PartitionSpec("data", None, "model", None)
 
 
+def test_decode_rules_shard_cache_heads_where_they_divide():
+    """codeqwen1.5-7b's 4 kv heads over a 4-way model axis: each chip holds
+    the cache of its own kv head, whole along the sequence."""
+    p = _plan("decode", (1, 4))
+    s = p.spec(("cache_batch", "cache_heads", "cache_seq", None),
+               (32, 4, 2304, 128))
+    assert s == PartitionSpec("data", "model", None, None)
+
+
 def test_sp_decode_rules_all_axes_on_seq():
     p = _plan("sp-decode")
     s = p.spec(("cache_batch", "cache_heads", "cache_seq", None),

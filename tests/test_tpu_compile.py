@@ -75,8 +75,12 @@ def test_wkv6_compiles_at_rwkv6_widths(one_chip, b, s, act):
 @pytest.mark.parametrize("hq,hkv,s", [
     (24, 8, 64),       # phi4-mini-3.8b, one serving prefill bucket
     (24, 8, 2048),
-    (32, 32, 64),      # codeqwen1.5-7b
+    (32, 32, 64),      # multi-head attention: as many kv heads as query heads
     (32, 32, 2048),
+    (32, 4, 64),       # codeqwen1.5-7b whole: 8 query heads per kv head
+    (32, 4, 2048),
+    (8, 1, 64),        # codeqwen1.5-7b per chip at tp4: 8 query heads, 1 kv
+    (8, 1, 2048),
 ])
 def test_flash_attention_compiles_at_model_head_layouts(one_chip, hq, hkv,
                                                         s):
@@ -115,6 +119,62 @@ def test_attention_runs_per_shard_on_four_chips(topo):
     text = _compiled_text(attend, q, q, q)
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+def test_codeqwen_tp4_programs_keep_the_cache_on_its_chip(topo):
+    """codeqwen1.5-7b at full width over a (1, 4) serve plan: each chip
+    holds one kv head of the cache, so the decode step and the largest
+    prefill move nothing between chips but the all-reduces after each
+    block's output projection (no all-to-all, no all-gather of K or V)."""
+    from repro.models.common import P
+    from repro.serving import ServingEngine
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    plan = ShardingPlan("decode", mesh, RULE_SETS["decode"](mesh.axis_names))
+    cfg = ARCHS["codeqwen1.5-7b"]
+    model = build_model(cfg, Variants(
+        attn_kernel=functools.partial(pallas_attention, interpret=False),
+        rms_norm=functools.partial(pallas_rmsnorm, interpret=False)))
+    slots, max_seq = 32, 2304
+
+    def placed(shapes, specs):
+        return jax.tree.map(
+            lambda a, p: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=plan.sharding(p.axes, p.shape)),
+            shapes, specs, is_leaf=lambda x: isinstance(x, P))
+
+    def cache(b):
+        return placed(jax.eval_shape(lambda: model.init_cache(b, max_seq)),
+                      model.cache_specs(b, max_seq))
+
+    def cache_shardings(b):
+        return jax.tree.map(lambda a: a.sharding, cache(b))
+
+    engine = object.__new__(ServingEngine)   # the programs, not the cache
+    engine.model, engine.plan, engine.max_seq = model, plan, max_seq
+    params = placed(model.param_shapes(), model.specs)
+    whole = NamedSharding(mesh, PartitionSpec())
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=whole)
+    assert cache(slots)["blocks"]["k"].sharding.spec[2] == "model"
+    decode = jax.jit(engine._decode_impl,
+                     out_shardings=(None, cache_shardings(slots)),
+                     donate_argnums=(1,)).lower(
+        params, cache(slots), sd((slots,), jnp.int32),
+        sd((slots,), jnp.int32), jax.random.PRNGKey(0),
+        sd((slots,), jnp.float32)).compile()
+    prefill = jax.jit(engine._prefill_impl,
+                      out_shardings=(None, cache_shardings(1))).lower(
+        params, sd((1, 2048), jnp.int32), sd((), jnp.int32)).compile()
+    for compiled in (decode, prefill):
+        text = compiled.as_text()
+        assert "all-reduce" in text
+        assert " all-to-all(" not in text and "all-to-all-start" not in text
+        assert not re.search(r"bf16\[[^\]]*128\][^=]* all-gather", text)
+    assert "tpu_custom_call" in prefill.as_text()
+    # a quarter of the weights and of the cache on each chip, little else
+    mem = decode.memory_analysis()
+    assert mem.alias_size_in_bytes == 32 * slots * 1 * max_seq * 128 * 2 * 2
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
 
 
 def test_rwkv6_decode_updates_its_state_in_place(one_chip):
